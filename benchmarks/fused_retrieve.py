@@ -4,8 +4,9 @@ Three halves, one claim (ROADMAP item 2 / RAGO's stage-fusion argument —
 the retrieve hot path should move only the bytes the search fundamentally
 requires):
 
-* **Equivalence** — the ``use_kernel="fused"`` backend must be bit-exact
-  (ids *and* scores) against the reference ladder on every
+* **Equivalence** — the ``use_kernel="fused"`` backend must meet the
+  cross-mode contract (``repro.kernels.ref.topk_mismatch``: equal ids,
+  scores equal up to f32 reduction order) against the reference ladder on every
   index_type×quant config, both freshly built and after mutations
   (tombstones + fresh inserts in the hybrid buffer), under both
   ``REPRO_KERNEL_MODE=interpret`` (Pallas kernels) and ``=xla`` (scan
@@ -64,11 +65,12 @@ CONFIGS = [("flat", "none"), ("flat", "sq8"), ("flat", "pq"),
 
 
 def equivalence(n: int = 512, dim: int = 32, k: int = 8) -> List[Dict]:
-    """Fused vs reference ladder, bit-exact, pre and post mutation."""
+    """Fused vs reference ladder, pre and post mutation."""
     import jax.numpy as jnp
 
     from repro.core.interfaces import Chunk
     from repro.core.vectordb import DBConfig, JaxVectorDB
+    from repro.kernels.ref import topk_mismatch
 
     vecs, q = _corpus(n, dim)
     qj = jnp.asarray(q)
@@ -105,8 +107,9 @@ def equivalence(n: int = 512, dim: int = 32, k: int = 8) -> List[Dict]:
                                  for i in range(len(fresh))])
                     sa, ia = ref._search_arrays(qj, k)
                     sb, ib = fus._search_arrays(qj, k)
-                    exact[phase] = float((ia == ib).all()
-                                         and (sa == sb).all())
+                    exact[phase] = float(
+                        (np.asarray(ia) == np.asarray(ib)).all()
+                        and topk_mismatch(sa, ia, sb, ib) is None)
                 rows.append({
                     "bench": (f"fused_retrieve/equiv_{mode}_"
                               f"{index_type}_{quant}"),
@@ -194,17 +197,21 @@ def latency(smoke: bool = False) -> List[Dict]:
             rng.standard_normal((m, 256, d // m)), jnp.float32)
         pcodes = jnp.asarray(
             rng.integers(0, 256, (nlist * cap_b, m)), jnp.int32)
-        pslot = jnp.asarray(np.arange(nlist * cap_b, dtype=np.int32))
-        pok = jnp.asarray((rng.random(nlist * cap_b) < 0.95).astype(np.int8))
+        # the packed mirror's layout: codes subspace-major, [1, rows] rows
+        pcodes_sm = pcodes.T
+        pslot = jnp.asarray(np.arange(nlist * cap_b, dtype=np.int32)[None])
+        pok = jnp.asarray(
+            (rng.random((1, nlist * cap_b)) < 0.95).astype(np.int32))
         # unfused reference over the identical layout (buckets == packed
         # rows, so both paths score exactly the same candidates)
         buckets = jnp.asarray(
             np.arange(nlist * cap_b, dtype=np.int32).reshape(nlist, cap_b))
         t_un = _time(lambda: _pq_ivf_search(
-            q, pcodes, codebook, pok.astype(bool), cent, buckets,
+            q, pcodes, codebook, pok[0].astype(bool), cent, buckets,
             buckets >= 0, nprobe, k))
         t_fu = _time(lambda: kops.fused_pq_topk(
-            q, codebook, cent, pcodes, pslot, pok, nprobe, k, mode="xla"))
+            q, codebook, cent, pcodes_sm, pslot, pok, nprobe, k,
+            mode="xla"))
         rows.append({
             "bench": "fused_retrieve/latency_pq",
             "nq": nq, "nlist": nlist, "cap_b": cap_b, "nprobe": nprobe,
@@ -264,7 +271,7 @@ def main(argv=None) -> int:
         if errs:
             print("CHECK FAILED:", "; ".join(errs))
             return 1
-        print("CHECK OK: fused backend bit-exact on all "
+        print("CHECK OK: fused backend matches the reference on all "
               f"{len(CONFIGS)} configs x 2 modes (incl. post-mutation), "
               "HBM bytes strictly closer to the bandwidth bound, "
               f"micro-batch speedup >= {MIN_SPEEDUP}x (sq8 + pq)")

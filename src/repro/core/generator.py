@@ -81,6 +81,12 @@ class GenStats:
             self.tokens_out += int(tokens)
             self.n_requests += 1
 
+    def reset(self) -> None:
+        """Drop every sample (a serving run's warm-up is not a request)."""
+        with self._lock:
+            self.ttft_s, self.tpot_s = [], []
+            self.tokens_out = self.n_requests = 0
+
     def merge(self, other: "GenStats") -> None:
         """Fold another stats object in (per-engine stats at summary time)."""
         with other._lock:
@@ -101,6 +107,7 @@ class GenStats:
             "tpot_mean_s": float(np.mean(tpot)) if tpot else 0.0,
             "ttft_p50_s": float(np.percentile(ttft, 50)) if ttft else 0.0,
             "ttft_p95_s": float(np.percentile(ttft, 95)) if ttft else 0.0,
+            "tpot_p50_s": float(np.percentile(tpot, 50)) if tpot else 0.0,
             "tpot_p95_s": float(np.percentile(tpot, 95)) if tpot else 0.0,
             "tokens_out": float(tokens),
             "n_requests": float(n),

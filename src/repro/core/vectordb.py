@@ -31,6 +31,7 @@ import numpy as np
 from repro.core.interfaces import Chunk, DBInstance, SearchResult
 from repro.core.registry import register
 from repro.kernels import ops as kops
+from repro.kernels.ref import HIGHEST
 
 NEG = np.float32(-3.0e38)
 
@@ -106,7 +107,7 @@ def _flat_search(q, vecs, live, k: int, kernel: str = "off",
         return kops.fused_flat_topk(q, vecs, live, k, mode=mode)
     if kernel == "op":
         return kops.topk_search(q, vecs, live, k, mode=mode)
-    scores = q @ vecs.T                                   # [nq, cap]
+    scores = jnp.dot(q, vecs.T, precision=HIGHEST)        # [nq, cap]
     scores = jnp.where(live[None, :], scores, NEG)
     top, idx = jax.lax.top_k(scores, k)
     return top, jnp.where(top <= NEG / 2, -1, idx)
@@ -118,13 +119,13 @@ def _ivf_search(q, vecs, live, cent, buckets, bucket_live, nprobe: int, k: int):
 
     buckets: [nlist, cap_b] int32 slot ids (-1 pad); bucket_live likewise bool.
     """
-    cscores = q @ cent.T                                  # [nq, nlist]
+    cscores = jnp.dot(q, cent.T, precision=HIGHEST)       # [nq, nlist]
     _, probe = jax.lax.top_k(cscores, nprobe)             # [nq, nprobe]
     cand = buckets[probe]                                 # [nq, nprobe, cap_b]
     cand_ok = bucket_live[probe] & (cand >= 0)
     cand_safe = jnp.maximum(cand, 0)
     cvecs = vecs[cand_safe]                               # [nq, np, cap_b, d]
-    scores = jnp.einsum("qd,qpbd->qpb", q, cvecs)
+    scores = jnp.einsum("qd,qpbd->qpb", q, cvecs, precision=HIGHEST)
     ok = cand_ok & live[cand_safe]
     scores = jnp.where(ok, scores, NEG)
     nq = q.shape[0]
@@ -163,8 +164,9 @@ def _pq_ivf_search(q, codes, codebook, live, cent, buckets, bucket_live,
     m, _, dsub = codebook.shape
     nq = q.shape[0]
     qs = q.reshape(nq, m, dsub)
-    lut = jnp.einsum("qms,mcs->qmc", qs, codebook)        # [nq, m, 256]
-    cscores = q @ cent.T
+    lut = jnp.einsum("qms,mcs->qmc", qs, codebook,
+                     precision=HIGHEST)                   # [nq, m, 256]
+    cscores = jnp.dot(q, cent.T, precision=HIGHEST)
     _, probe = jax.lax.top_k(cscores, nprobe)
     cand = buckets[probe]                                 # [nq, np, cap_b]
     cand_ok = bucket_live[probe] & (cand >= 0)
@@ -408,13 +410,21 @@ class JaxVectorDB(DBInstance):
 
         ``slot`` maps packed row -> original slot id (-1 pad); the gathered
         vectors/codes rows are copies, so later tombstones only affect the
-        search-time ``ok`` mask, never the mirrored data.
+        search-time ``ok`` mask, never the mirrored data.  The mirror is
+        kept in the kernels' layout: buckets padded to a multiple of 128
+        rows, ``slot`` as one ``[1, rows]`` int32 row (a bucket's slots are
+        one lane-dense ``(1, cap_b)`` block), PQ codes subspace-major
+        ``[m, rows]`` int32.
         """
-        slot = self.buckets.reshape(-1).astype(np.int32)
-        safe = np.maximum(slot, 0)
+        nlist, cap_b = self.buckets.shape
+        slot = np.full((nlist, -(-cap_b // 128) * 128), -1, np.int32)
+        slot[:, :cap_b] = self.buckets
+        slot = slot.reshape(1, -1)
+        safe = np.maximum(slot[0], 0)
         packed: Dict[str, np.ndarray] = {"slot": slot}
         if self.cfg.quant == "pq" and self.pq_codes is not None:
-            packed["codes"] = self.pq_codes[safe]
+            packed["codes"] = np.ascontiguousarray(
+                self.pq_codes[safe].T, dtype=np.int32)
         else:
             packed["vecs"] = self.vectors[safe]
         self.packed = packed
@@ -565,7 +575,7 @@ class JaxVectorDB(DBInstance):
         """
         packed = snap["packed"]
         slot = packed["slot"]
-        ok = ((slot >= 0) & main_live[np.maximum(slot, 0)]).astype(np.int8)
+        ok = ((slot >= 0) & main_live[np.maximum(slot, 0)]).astype(np.int32)
         if self.cfg.quant == "pq" and packed.get("codes") is not None:
             return kops.fused_pq_topk(
                 q, jnp.asarray(snap["pq_codebook"]),

@@ -18,14 +18,15 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
+
+from repro.kernels.ref import HIGHEST
 
 NEG = -3.0e38
 
 
 def local_topk(q, vecs, live, k: int):
-    scores = q @ vecs.T
+    scores = jnp.dot(q, vecs.T, precision=HIGHEST)
     scores = jnp.where(live[None, :], scores, NEG)
     rows = scores.shape[1]
     if k > rows:
@@ -62,10 +63,10 @@ def make_sharded_topk(mesh: Mesh, k: int, corpus_axes=("pod", "data")):
         return top, jnp.where(top <= NEG / 2, -1, idx)
 
     vspec = P(axes if len(axes) > 1 else (axes[0] if axes else None))
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(P(), vspec, vspec),
-                   out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P(), vspec, vspec),
+                       out_specs=(P(), P()),
+                       check_vma=False)
     return jax.jit(fn), n_shards
 
 

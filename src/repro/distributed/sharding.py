@@ -61,10 +61,6 @@ def sharding_rules(mesh: Optional[Mesh], rules: Optional[Dict[str, Axis]] = None
         _STATE.mesh, _STATE.rules = prev
 
 
-def active_mesh() -> Optional[Mesh]:
-    return _STATE.mesh
-
-
 def _axis_size(mesh: Mesh, axis: Axis) -> int:
     if axis is None:
         return 1

@@ -24,10 +24,13 @@ The fused kernels keep every intermediate in VMEM:
   probe selection is a tiny ``[nq, nlist]`` XLA prologue whose winners feed
   the kernel as a *scalar-prefetch* operand: grid step ``(i, p)`` DMAs
   exactly the probed bucket's block into VMEM via the prefetched index map,
-  scores it against query ``i`` (PQ: ADC gather from the per-query LUT,
-  resident in VMEM), and selects the bucket-local top-k.  The
-  ``[nq, nprobe, cap_b]`` candidate tensors of the unfused path never
-  exist; ``[nq, nprobe, k]`` candidates merge outside.
+  scores it against query ``i`` (PQ: ADC lookup in the per-query LUT,
+  resident in VMEM, as one-hot matmuls), and selects the bucket-local
+  top-k.  Buckets hold a multiple of 128 rows, and the mirror keeps the
+  kernels' layout (``[1, rows]`` slot ids, ``[m, rows]`` PQ codes) so no
+  search converts it.  The ``[nq, nprobe, cap_b]`` candidate tensors of
+  the unfused path never exist; ``[nq, nprobe, k]`` candidates merge
+  outside.
 
 Every kernel is batched over the query axis, so one coalesced retrieve
 micro-batch from the elastic executor is a single kernel launch.
@@ -35,9 +38,10 @@ micro-batch from the elastic executor is a single kernel launch.
 Modes: the ``pallas`` variants compile on TPU and validate under
 ``interpret=True`` on CPU; the ``*_xla`` fallbacks implement the *same
 tiled algorithm* (per-tile score → local top-k → merge) with ``lax.scan``
-carrying only tile-sized intermediates, so outputs are identical across
-modes and the CPU benchmark path still avoids materializing the full
-matrices.  Dispatch lives in ``repro.kernels.ops``.
+carrying only tile-sized intermediates, so outputs agree across modes
+under the contract in ``repro.kernels.ref`` (equal ids, scores equal up to
+f32 reduction order) and the CPU benchmark path still avoids materializing
+the full matrices.  Dispatch lives in ``repro.kernels.ops``.
 
 Output contract (shared with ``topk_search_pallas``): rows with fewer than
 ``k`` live matches pad with ``(NEG, -1)`` — masked/dead candidates score
@@ -53,24 +57,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG = -3.0e38
-
-
-def merge_candidates(cand_s, cand_i, k: int):
-    """Global top-k over per-tile/per-bucket candidates.
-
-    ``cand_s``/``cand_i``: ``[nq, C]`` candidate scores/ids in tile-major,
-    rank-minor order (ties therefore resolve exactly as a flat
-    ``lax.top_k`` over the unfused score matrix would).  Pads with
-    ``(NEG, -1)`` when ``C < k``.
-    """
-    nq, c = cand_s.shape
-    if c < k:
-        cand_s = jnp.pad(cand_s, ((0, 0), (0, k - c)), constant_values=NEG)
-        cand_i = jnp.pad(cand_i, ((0, 0), (0, k - c)), constant_values=-1)
-    top, pos = jax.lax.top_k(cand_s, k)
-    idx = jnp.take_along_axis(cand_i, pos, axis=1)
-    return top, jnp.where(top <= NEG / 2, -1, idx)
+from repro.kernels.ref import HIGHEST
+from repro.kernels.topk_search import (NEG, lanes, merge_candidates,
+                                       merge_tiles, select_topk,
+                                       topk_search_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -78,67 +68,15 @@ def merge_candidates(cand_s, cand_i, k: int):
 # ---------------------------------------------------------------------------
 
 
-def _sq8_tile_kernel(qs_ref, codes_ref, live_ref, out_s_ref, out_i_ref, *,
-                     k: int, bn: int):
-    """One grid step: dequant-score one (bq × bn) int8 tile, emit its
-    local top-k.  Codes upcast int8→f32 in VMEM — HBM only ever sees the
-    1-byte codes."""
-    j = pl.program_id(1)
-    qs = qs_ref[...]                                   # [bq, d] f32 prescaled
-    codes = codes_ref[...].astype(jnp.float32)         # [bn, d] int8 -> f32
-    live = live_ref[...]                               # [bn] int8
-    scores = jax.lax.dot_general(
-        qs, codes, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)            # [bq, bn] on the MXU
-    scores = jnp.where(live[None, :] != 0, scores, NEG)
-    base = j * bn
-    col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    def body(t, carry):
-        scores, col = carry
-        m = jnp.max(scores, axis=1)
-        am = jnp.argmax(scores, axis=1)
-        out_s_ref[:, 0, t] = m
-        out_i_ref[:, 0, t] = (base + am).astype(jnp.int32)
-        return jnp.where(col == am[:, None], NEG, scores), col
-
-    jax.lax.fori_loop(0, k, body, (scores, col))
-
-
-@functools.partial(jax.jit, static_argnames=("k", "bq", "bn", "interpret"))
 def sq8_topk_pallas(q, codes, scale, live, k: int, *, bq: int = 128,
                     bn: int = 1024, interpret: bool = True):
     """q:[nq,d] f32, codes:[N,d] int8, scale:[d], live:[N]
-    -> (scores [nq,k], idx [nq,k]) with (NEG, -1) padding."""
-    nq, d = q.shape
-    N = codes.shape[0]
-    qs = q * scale[None, :]
-    nq_p = -(-nq // bq) * bq
-    n_p = -(-N // bn) * bn
-    qp = jnp.pad(qs, ((0, nq_p - nq), (0, 0)))
-    cp = jnp.pad(codes, ((0, n_p - N), (0, 0)))
-    lp = jnp.pad(live.astype(jnp.int8), (0, n_p - N))
-    nt = n_p // bn
-    out_s, out_i = pl.pallas_call(
-        functools.partial(_sq8_tile_kernel, k=k, bn=bn),
-        grid=(nq_p // bq, nt),
-        in_specs=[
-            pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bq, 1, k), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bq, 1, k), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq_p, nt, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq_p, nt, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(qp, cp, lp)
-    return merge_candidates(out_s[:nq].reshape(nq, nt * k),
-                            out_i[:nq].reshape(nq, nt * k), k)
+    -> (scores [nq,k], idx [nq,k]) with (NEG, -1) padding.
+
+    The flat tile kernel over int8 codes: the scale folds into the query,
+    codes upcast int8→f32 in VMEM, so HBM only ever sees the 1-byte codes."""
+    return topk_search_pallas(q * scale[None, :], codes, live, k, bq=bq,
+                              bn=bn, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bn"))
@@ -158,7 +96,7 @@ def _tiled_topk_xla(qs, mat, live, k: int, bn: int):
         c, l, base = inp
         s = jax.lax.dot_general(
             qs, c.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [nq, bn]
+            precision=HIGHEST, preferred_element_type=jnp.float32)  # [nq, bn]
         s = jnp.where(l[None, :], s, NEG)
         ts, tp = jax.lax.top_k(s, kt)
         return carry, (ts, (base + tp).astype(jnp.int32))
@@ -188,37 +126,17 @@ def sq8_topk_xla(q, codes, scale, live, k: int, *, bn: int = 1024):
 # ---------------------------------------------------------------------------
 
 
-def _bucket_topk(scores, slot, out_s_ref, out_i_ref, k: int):
-    """k rounds of (max, argmax, mask) over one probed bucket's VMEM tile.
-
-    ``scores``: [1, cap_b]; ``slot``: [cap_b] original slot ids (the packed
-    mirror's row -> slot map), emitted for the winners."""
-    col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    def body(t, carry):
-        sc, = carry
-        m = jnp.max(sc, axis=1)
-        am = jnp.argmax(sc, axis=1)
-        out_s_ref[0, 0, t] = m[0]
-        out_i_ref[0, 0, t] = slot[am[0]]
-        return (jnp.where(col == am[:, None], NEG, sc),)
-
-    jax.lax.fori_loop(0, k, body, (scores,))
-
-
 def _ivf_bucket_kernel(probe_ref, q_ref, vecs_ref, ok_ref, slot_ref,
                        out_s_ref, out_i_ref, *, k: int):
-    """Grid step (i, p): score query i against its p-th probed bucket."""
+    """Grid step (i, p): score query i against its p-th probed bucket and
+    emit the bucket-local top-k as one lane-dense row."""
     del probe_ref                     # consumed by the index maps
-    q = q_ref[...]                    # [1, d]
-    vecs = vecs_ref[...]              # [cap_b, d]
-    ok = ok_ref[...]                  # [cap_b] int8
-    slot = slot_ref[...]              # [cap_b] int32
     scores = jax.lax.dot_general(
-        q, vecs, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)            # [1, cap_b]
-    scores = jnp.where(ok[None, :] != 0, scores, NEG)
-    _bucket_topk(scores, slot, out_s_ref, out_i_ref, k)
+        q_ref[...], vecs_ref[...], (((1,), (1,)), ((), ())),
+        precision=HIGHEST,
+        preferred_element_type=jnp.float32)            # [1, d]·[cap_b, d]ᵀ
+    scores = jnp.where(ok_ref[...] != 0, scores, NEG)  # ok: [1, cap_b]
+    out_s_ref[...], out_i_ref[...] = select_topk(scores, k, ids=slot_ref[...])
 
 
 def _probe(q, cent, nprobe: int):
@@ -226,7 +144,7 @@ def _probe(q, cent, nprobe: int):
 
     Identical arithmetic to the unfused ``_ivf_search`` probe, so the
     fused path scores exactly the same buckets."""
-    _, probe = jax.lax.top_k(q @ cent.T, nprobe)
+    _, probe = jax.lax.top_k(jnp.dot(q, cent.T, precision=HIGHEST), nprobe)
     return probe.astype(jnp.int32)
 
 
@@ -236,42 +154,44 @@ def ivf_topk_pallas(q, cent, packed_vecs, packed_slot, packed_ok,
     """IVF probe→score→select over the packed mirror, one launch.
 
     q:[nq,d]; cent:[nlist,d]; packed_vecs:[nlist*cap_b,d];
-    packed_slot/packed_ok:[nlist*cap_b] (slot id / liveness of each packed
-    row, -1 / 0 for pads and tombstones).
+    packed_slot/packed_ok:[1, nlist*cap_b] int32 (slot id / liveness of
+    each packed row, -1 / 0 for pads and tombstones); cap_b a multiple of
+    128.
 
-    Per-query blocks are (1, d): bucket membership differs per query, so
-    the MXU tile is inherently narrow — the win is bandwidth (validated in
-    interpret mode; see module docstring).
+    Each grid step reads one query as a ``(1, d)`` block of ``[nq, 1, d]``:
+    bucket membership differs per query, so the MXU tile is inherently
+    narrow — the win is bandwidth.
     """
     nq, d = q.shape
     nlist = cent.shape[0]
     cap_b = packed_vecs.shape[0] // nlist
+    kp = lanes(k)
     probe = _probe(q, cent, nprobe)
+    row = lambda i, p, probe: (0, probe[i, p])           # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nq, nprobe),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, p, probe: (i, 0)),
+            pl.BlockSpec((None, 1, d), lambda i, p, probe: (i, 0, 0)),
             pl.BlockSpec((cap_b, d), lambda i, p, probe: (probe[i, p], 0)),
-            pl.BlockSpec((cap_b,), lambda i, p, probe: (probe[i, p],)),
-            pl.BlockSpec((cap_b,), lambda i, p, probe: (probe[i, p],)),
+            pl.BlockSpec((1, cap_b), row),
+            pl.BlockSpec((1, cap_b), row),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, k), lambda i, p, probe: (i, p, 0)),
-            pl.BlockSpec((1, 1, k), lambda i, p, probe: (i, p, 0)),
+            pl.BlockSpec((None, 1, kp), lambda i, p, probe: (i, 0, p)),
+            pl.BlockSpec((None, 1, kp), lambda i, p, probe: (i, 0, p)),
         ],
     )
     out_s, out_i = pl.pallas_call(
         functools.partial(_ivf_bucket_kernel, k=k),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((nq, nprobe, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq, nprobe, k), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1, nprobe * kp), jnp.float32),
+            jax.ShapeDtypeStruct((nq, 1, nprobe * kp), jnp.int32),
         ],
         interpret=interpret,
-    )(probe, q, packed_vecs, packed_ok, packed_slot)
-    return merge_candidates(out_s.reshape(nq, nprobe * k),
-                            out_i.reshape(nq, nprobe * k), k)
+    )(probe, q[:, None, :], packed_vecs, packed_ok, packed_slot)
+    return merge_tiles(out_s[:, 0], out_i[:, 0], nprobe, k)
 
 
 @functools.partial(jax.jit, static_argnames=("nprobe", "k"))
@@ -290,10 +210,7 @@ def ivf_topk_xla(q, cent, packed_vecs, packed_slot, packed_ok,
 
     def per_probe(carry, p):
         b = probe[:, p]                                # [nq]
-        # keep a size-1 probe axis: the two-batch-dim dot_general then
-        # lowers with the same d-reduction order as the unfused
-        # ``qd,qpbd->qpb`` einsum, preserving bit-exact score parity
-        s = jnp.einsum("qd,qpbd->qpb", q, pv[b][:, None])[:, 0]
+        s = jnp.einsum("qd,qbd->qb", q, pv[b], precision=HIGHEST)
         s = jnp.where(po[b] != 0, s, NEG)
         ts, tp = jax.lax.top_k(s, kt)
         return carry, (ts, jnp.take_along_axis(ps[b], tp, axis=1))
@@ -310,80 +227,73 @@ def _pq_lut(q, codebook):
     unfused ``_pq_ivf_search``)."""
     m, _, dsub = codebook.shape
     nq = q.shape[0]
-    return jnp.einsum("qms,mcs->qmc", q.reshape(nq, m, dsub), codebook)
-
-
-def adc_sum(gath):
-    """Sum gathered LUT values over the trailing subspace axis with a
-    *fixed* (sequential) association order.
-
-    ``jnp.sum`` leaves the reduction order to the backend — the compiled
-    XLA program and the Pallas interpreter pick different trees, which
-    costs 1-ulp score divergence across kernel modes and breaks the
-    bit-exact parity gate.  Unrolled adds (``m`` is small and static)
-    cannot be reassociated, so every mode — and the unfused reference in
-    ``repro.core.vectordb`` — produces identical bits.
-    """
-    out = gath[..., 0]
-    for t in range(1, gath.shape[-1]):
-        out = out + gath[..., t]
-    return out
+    return jnp.einsum("qms,mcs->qmc", q.reshape(nq, m, dsub), codebook,
+                      precision=HIGHEST)
 
 
 def _pq_bucket_kernel(probe_ref, lut_ref, codes_ref, ok_ref, slot_ref,
                       out_s_ref, out_i_ref, *, k: int):
     """Grid step (i, p): ADC-score query i's LUT against one bucket's codes.
 
-    The [m, 256] LUT is VMEM-resident; the gather is a VMEM table lookup
-    (validated in interpret mode)."""
+    The [m, 256] LUT is VMEM-resident.  The lookup is a one-hot matmul per
+    subspace — ``lut[j] · onehot(codes[j])`` picks exactly one table entry
+    per row on the MXU — summed over subspaces."""
     del probe_ref
-    lut = lut_ref[0]                  # [m, 256]
-    codes = codes_ref[...]            # [cap_b, m] int32
-    ok = ok_ref[...]
-    slot = slot_ref[...]
-    gath = jnp.take_along_axis(
-        jnp.broadcast_to(lut[None], (codes.shape[0],) + lut.shape),
-        codes[..., None], axis=2)[..., 0]              # [cap_b, m]
-    scores = adc_sum(gath)[None, :]                    # [1, cap_b]
-    scores = jnp.where(ok[None, :] != 0, scores, NEG)
-    _bucket_topk(scores, slot, out_s_ref, out_i_ref, k)
+    codes = codes_ref[...]                             # [m, cap_b] int32
+    m, cap_b = codes.shape
+    entry = jax.lax.broadcasted_iota(jnp.int32, (256, cap_b), 0)
+    scores = None
+    for j in range(m):
+        onehot = (entry == codes[j:j + 1, :]).astype(jnp.float32)
+        s = jax.lax.dot_general(
+            lut_ref[j:j + 1, :], onehot, (((1,), (0,)), ((), ())),
+            precision=HIGHEST,
+            preferred_element_type=jnp.float32)        # [1, cap_b]
+        scores = s if scores is None else scores + s
+    scores = jnp.where(ok_ref[...] != 0, scores, NEG)
+    out_s_ref[...], out_i_ref[...] = select_topk(scores, k, ids=slot_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("nprobe", "k", "interpret"))
 def pq_topk_pallas(q, codebook, cent, packed_codes, packed_slot, packed_ok,
                    nprobe: int, k: int, *, interpret: bool = True):
-    """PQ ADC probe→score→select over packed bucket codes, one launch."""
+    """PQ ADC probe→score→select over packed bucket codes, one launch.
+
+    packed_codes:[m, nlist*cap_b] int32, subspace-major, so a probed
+    bucket is one lane-dense ``(m, cap_b)`` block; packed_slot/packed_ok
+    as in ``ivf_topk_pallas``."""
     nq = q.shape[0]
     m = codebook.shape[0]
     nlist = cent.shape[0]
-    cap_b = packed_codes.shape[0] // nlist
+    cap_b = packed_codes.shape[1] // nlist
+    kp = lanes(k)
     lut = _pq_lut(q, codebook)
     probe = _probe(q, cent, nprobe)
+    row = lambda i, p, probe: (0, probe[i, p])           # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nq, nprobe),
         in_specs=[
-            pl.BlockSpec((1, m, 256), lambda i, p, probe: (i, 0, 0)),
-            pl.BlockSpec((cap_b, m), lambda i, p, probe: (probe[i, p], 0)),
-            pl.BlockSpec((cap_b,), lambda i, p, probe: (probe[i, p],)),
-            pl.BlockSpec((cap_b,), lambda i, p, probe: (probe[i, p],)),
+            pl.BlockSpec((None, m, 256), lambda i, p, probe: (i, 0, 0)),
+            pl.BlockSpec((m, cap_b), row),
+            pl.BlockSpec((1, cap_b), row),
+            pl.BlockSpec((1, cap_b), row),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, k), lambda i, p, probe: (i, p, 0)),
-            pl.BlockSpec((1, 1, k), lambda i, p, probe: (i, p, 0)),
+            pl.BlockSpec((None, 1, kp), lambda i, p, probe: (i, 0, p)),
+            pl.BlockSpec((None, 1, kp), lambda i, p, probe: (i, 0, p)),
         ],
     )
     out_s, out_i = pl.pallas_call(
         functools.partial(_pq_bucket_kernel, k=k),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((nq, nprobe, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq, nprobe, k), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1, nprobe * kp), jnp.float32),
+            jax.ShapeDtypeStruct((nq, 1, nprobe * kp), jnp.int32),
         ],
         interpret=interpret,
     )(probe, lut, packed_codes, packed_ok, packed_slot)
-    return merge_candidates(out_s.reshape(nq, nprobe * k),
-                            out_i.reshape(nq, nprobe * k), k)
+    return merge_tiles(out_s[:, 0], out_i[:, 0], nprobe, k)
 
 
 @functools.partial(jax.jit, static_argnames=("nprobe", "k"))
@@ -395,25 +305,25 @@ def pq_topk_xla(q, codebook, cent, packed_codes, packed_slot, packed_ok,
     The ADC lookup indexes a *flattened* per-query ``[m*256]`` table
     (``code + 256*subspace``): one single-axis take_along_axis, which XLA
     CPU lowers ~4x faster than the rank-3 broadcast gather while fetching
-    bit-identical values.
+    the same values.
     """
     nq = q.shape[0]
     m = codebook.shape[0]
     nlist = cent.shape[0]
-    cap_b = packed_codes.shape[0] // nlist
+    cap_b = packed_codes.shape[1] // nlist
     flat_lut = _pq_lut(q, codebook).reshape(nq, m * 256)
     probe = _probe(q, cent, nprobe)
-    pc = packed_codes.reshape(nlist, cap_b, m)
+    pc = packed_codes.reshape(m, nlist, cap_b)
     ps = packed_slot.reshape(nlist, cap_b)
     po = packed_ok.reshape(nlist, cap_b)
-    offs = (jnp.arange(m, dtype=packed_codes.dtype) * 256)[None, None, :]
+    offs = (jnp.arange(m, dtype=packed_codes.dtype) * 256)[:, None, None]
     kt = min(k, cap_b)
 
     def per_probe(carry, p):
         b = probe[:, p]
-        fidx = (pc[b] + offs).reshape(nq, cap_b * m)
+        fidx = jnp.moveaxis(pc[:, b] + offs, 0, 1).reshape(nq, m * cap_b)
         gath = jnp.take_along_axis(flat_lut, fidx, axis=1)
-        s = adc_sum(gath.reshape(nq, cap_b, m))        # [nq, cap_b]
+        s = gath.reshape(nq, m, cap_b).sum(axis=1)     # [nq, cap_b]
         s = jnp.where(po[b] != 0, s, NEG)
         ts, tp = jax.lax.top_k(s, kt)
         return carry, (ts, jnp.take_along_axis(ps[b], tp, axis=1))

@@ -22,8 +22,10 @@ Dispatch contract (the "kernel-dispatch" invariants pinned by
   static argument — an environment read at trace time would be baked into the
   jit cache and a later ``REPRO_KERNEL_MODE`` change would silently not take
   effect for already-traced shapes.
-* All modes of one op return identical results, including the documented
-  ``(NEG, -1)`` padding for rows with fewer than ``k`` live matches.
+* All modes of one op meet the cross-mode contract of
+  ``repro.kernels.ref.topk_mismatch`` (equal ids, scores equal up to f32
+  reduction order), including the documented ``(NEG, -1)`` padding for
+  rows with fewer than ``k`` live matches.
 """
 from __future__ import annotations
 

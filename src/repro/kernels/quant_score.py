@@ -17,12 +17,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import HIGHEST
+
 
 def _quant_score_kernel(qs_ref, codes_ref, out_ref):
     qs = qs_ref[...]                                   # [bq, d] f32 (prescaled)
     codes = codes_ref[...].astype(jnp.float32)         # [bn, d] int8 -> f32
+    # HIGHEST: Mosaic's default f32 contraction is not full f32 on TPU
     out_ref[...] = jax.lax.dot_general(
-        qs, codes, (((1,), (1,)), ((), ())),
+        qs, codes, (((1,), (1,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)            # [bq, bn]
 
 

@@ -9,19 +9,12 @@ intra-pod where ICI bandwidth is (DESIGN.md §6).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed after jax 0.4.37; older jax only has Auto semantics
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        axis_types = (AxisType.Auto,) * len(axes)
-        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=axis_types)
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -38,6 +38,7 @@ import time
 from repro.core.pipeline import PipelineConfig
 from repro.core.registry import build
 from repro.core.spec import GenSpec, PipelineSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.metrics.quality import evaluate_traces
 from repro.monitor.monitor import MonitorConfig, ResourceMonitor
 from repro.obs import (MetricsRegistry, Tracer, VirtualClock, WallClock,
@@ -143,7 +144,8 @@ def run_scenario(args) -> None:
         print(f"wrote {args.json_out}")
 
 
-def main(argv=None):
+def main(argv=None) -> int:
+    """Returns the exit status: non-zero when any request failed."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="",
                     help="PipelineSpec JSON; overrides the legacy flags")
@@ -223,8 +225,10 @@ def main(argv=None):
     # (a scenario's own seed must only be overridden explicitly)
     ap.add_argument("--seed", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.scenario:
-        return run_scenario(args)
+        run_scenario(args)
+        return 0
     if args.seed is None:
         args.seed = 0
     if args.target_qps <= 0:
@@ -293,6 +297,8 @@ def main(argv=None):
         # warm the jit caches so compile time doesn't pollute the tail
         pipe.query(["warmup query"])
         pipe.traces.clear()
+        if hasattr(getattr(pipe.llm, "stats", None), "reset"):
+            pipe.llm.stats.reset()      # the warm-up is not a request
         scfg = ServingConfig(
             arrival=ArrivalConfig(
                 mode=args.mode, process=args.arrival,
@@ -335,13 +341,14 @@ def main(argv=None):
         s = res.summary
         if args.mode == "open":
             print(f"offered {s.get('offered_qps', 0.0):.2f} QPS "
-                  f"({args.arrival}), achieved {s['achieved_qps']:.2f} QPS")
+                  f"({args.arrival}), achieved "
+                  f"{s.get('achieved_qps', 0.0):.2f} QPS")
         else:
             print(f"closed-loop concurrency={args.concurrency}: "
-                  f"achieved {s['achieved_qps']:.2f} QPS "
+                  f"achieved {s.get('achieved_qps', 0.0):.2f} QPS "
                   f"(peak in-flight {res.peak_in_flight})")
-        # .get defaults: a query-free workload (--update-frac 1.0) has no
-        # latency percentiles to report
+        # .get defaults: a query-free workload (--update-frac 1.0), or one
+        # whose every request failed, has no rates or percentiles to report
         print(f"latency p50/p95/p99 (ms): {s.get('p50_latency_ms', 0.0):.1f} / "
               f"{s.get('p95_latency_ms', 0.0):.1f} / "
               f"{s.get('p99_latency_ms', 0.0):.1f}")
@@ -429,11 +436,18 @@ def main(argv=None):
 
     if args.json_out:
         json_doc["stage_breakdown"] = pipe.breakdown()
+        json_doc["db"] = pipe.db.stats()
         os.makedirs(os.path.dirname(args.json_out) or ".", exist_ok=True)
         with open(args.json_out, "w") as f:
             json.dump(json_doc, f, indent=2, sort_keys=True)
         print(f"wrote {args.json_out}")
+    n_failed = int(json_doc.get("summary", {}).get("n_failed", 0))
+    if n_failed:
+        # no faults are injected on this path: a failure is a real error
+        print(f"FAILED: {n_failed} request(s) failed (tracebacks above)")
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

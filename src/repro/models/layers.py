@@ -25,8 +25,11 @@ def _dt(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
 
 
+@partial(jax.jit, static_argnames=("shape", "dtype", "scale"))
 def dense_init(key, shape, dtype, scale: Optional[float] = None):
-    """Truncated-normal fan-in init."""
+    """Truncated-normal fan-in init, compiled as one program so the f32
+    draw never sits on the device beside its scaled copy (a full-width
+    weight stack is several GB in f32)."""
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     std = scale if scale is not None else 1.0 / math.sqrt(fan_in)
     return (jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32) * std).astype(dtype)

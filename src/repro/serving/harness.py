@@ -21,6 +21,7 @@ time-series traces as RSS/CPU/device memory.
 """
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from dataclasses import dataclass, field
@@ -36,6 +37,8 @@ from repro.serving.batcher import BatchPolicy, ContinuousBatcher, Submission
 from repro.workload.corpus import SyntheticCorpus
 from repro.workload.generator import Request, WorkloadConfig, WorkloadGenerator
 from repro.workload.runner import gold_chunks_for
+
+log = logging.getLogger(__name__)
 
 
 @dataclass
@@ -205,6 +208,10 @@ class ServingHarness:
                 elif req.op == "removal":
                     self.pipeline.remove_document(req.doc_id)
         except Exception as e:                      # noqa: BLE001
+            # isolate the batch, but say why: a compile or device error
+            # must not pass for a bad error rate
+            log.exception("batch of %d %s request(s) failed", len(batch),
+                          batch[0].request.op)
             for sub in batch:
                 self._finish(sub, ok=False, err=e)
             return

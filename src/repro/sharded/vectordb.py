@@ -21,10 +21,15 @@ Design
 - **Search** — each shard computes a local top-k against a *consistent
   cross-shard snapshot* (all shard snapshots taken under one wrapper lock),
   then lists fold pairwise through ``merge_topk`` — only O(shards·k)
-  winners cross shard boundaries, never full score matrices.  When a JAX
-  mesh with matching ``corpus`` axes is active and the index is a plain
-  flat scan, search instead runs the fused ``make_sharded_topk`` shard_map
-  path over one device-sharded ``[n_shards·cap, d]`` array.
+  winners cross shard boundaries, never full score matrices.  A database
+  given a JAX mesh (a plain flat scan whose ``corpus`` axes hold exactly
+  ``n_shards`` devices; anything else raises at construction) instead runs
+  the fused ``make_sharded_topk`` shard_map path over one
+  ``[n_shards·cap, d]`` array placed row-sharded on the mesh (one shard
+  per device).  The mesh is an attribute of the database, not of the
+  calling thread, so serving worker threads take the mesh path too.  The
+  registered ``sharded`` factory builds that mesh itself when the shard
+  count equals the device count.
 - **Mutations** — the elastic executor's serialized writer calls
   ``insert``/``remove``/``update`` here; the wrapper groups the batch by
   target shard and applies groups shard-parallel (shards are independent,
@@ -43,14 +48,16 @@ from dataclasses import dataclass
 from typing import Callable, Dict, Iterator, List, Mapping, Optional, \
     Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.interfaces import Chunk, DBInstance, SearchResult
 from repro.core.registry import register
 from repro.core.vectordb import DBConfig, JaxVectorDB, NEG, merge_topk
 from repro.distributed.collectives import make_sharded_topk
-from repro.distributed.sharding import active_mesh
+from repro.launch.mesh import make_mesh
 
 
 def doc_shard(doc_id: int, n_shards: int) -> int:
@@ -88,7 +95,6 @@ class ShardedDBConfig:
     use_kernel: object = False
     train_sample: int = 16384
     balance_slack: float = 1.5       # per-shard headroom over an even split
-    use_mesh: bool = True            # fused shard_map scan when mesh matches
     corpus_axes: Tuple[str, ...] = ("pod", "data")
 
 
@@ -119,9 +125,22 @@ class _DocSlotsView(Mapping):
 class ShardedVectorDB(DBInstance):
     """N-way row-partitioned vector DB with O(shards·k) merge reduction."""
 
-    def __init__(self, cfg: ShardedDBConfig):
+    def __init__(self, cfg: ShardedDBConfig, mesh: Optional[Mesh] = None):
         assert cfg.n_shards >= 1, cfg.n_shards
         self.cfg = cfg
+        self.mesh = mesh               # device mesh for the shard_map scan
+        self._mesh_axes: Tuple[str, ...] = ()
+        if mesh is not None:
+            self._mesh_axes = tuple(a for a in cfg.corpus_axes
+                                    if a in mesh.shape)
+            size = int(np.prod([mesh.shape[a] for a in self._mesh_axes]))
+            if (cfg.index_type, cfg.quant) != ("flat", "none") or (
+                    not self._mesh_axes or size != cfg.n_shards):
+                raise ValueError(
+                    f"the mesh path scans a flat/none corpus with one shard "
+                    f"per device of the corpus axes {cfg.corpus_axes}; got "
+                    f"{cfg.index_type}/{cfg.quant}, {cfg.n_shards} shards, "
+                    f"mesh {dict(mesh.shape)}")
         self._mu = threading.RLock()   # cross-shard snapshot/mutation fence
         self.shards: List[JaxVectorDB] = [
             JaxVectorDB(self._shard_cfg()) for _ in range(cfg.n_shards)]
@@ -132,9 +151,9 @@ class ShardedVectorDB(DBInstance):
             "merge_time_s": 0.0,
         }
         self._epoch = 0                # guarded-by: _mu
-        # fused-path caches: jitted shard_map fn per (mesh, k) + stacked
-        # device arrays valid for one mutation epoch
-        self._mesh_fns: Dict[Tuple[int, int], Tuple[Callable, int]] = {}  # guarded-by: _mu
+        # fused-path caches: jitted shard_map fn per k + stacked device
+        # arrays valid for one mutation epoch
+        self._mesh_fns: Dict[int, Callable] = {}  # guarded-by: _mu
         self._mesh_arrays: Optional[Tuple[int, object, object]] = None   # guarded-by: _mu
         # optional obs.Tracer: fan-out/merge spans on the "db" thread lane
         self.tracer = None
@@ -292,42 +311,36 @@ class ShardedVectorDB(DBInstance):
 
     def _mesh_search(self, q, k: int, snaps, epoch: int
                      ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-        """Fused shard_map scan when a matching mesh is active.
+        """Fused shard_map scan over the database's mesh, if it has one.
 
-        Eligible only for the plain flat scan (exact over all live rows —
-        hybrid freshness folds in for free since flat main + flat buffer
-        together cover exactly ``live``); IVF/quantized paths fall back to
-        the host-side merge reduction.
+        The constructor admits a mesh only for the plain flat scan (exact
+        over all live rows — hybrid freshness folds in for free since flat
+        main + flat buffer together cover exactly ``live``); without a mesh
+        every index takes the host-side merge reduction.
         """
-        cfg = self.cfg
-        mesh = active_mesh() if cfg.use_mesh else None
-        if (mesh is None or cfg.index_type != "flat" or cfg.quant != "none"
-                or cfg.n_shards == 1):
+        mesh, axes = self.mesh, self._mesh_axes
+        if mesh is None:
             return None
-        axes = tuple(a for a in cfg.corpus_axes if a in mesh.shape)
-        if not axes:
-            return None
-        size = int(np.prod([mesh.shape[a] for a in axes]))
-        if size != cfg.n_shards:
-            return None
-        key = (id(mesh), k)
         with self._mu:
-            if key not in self._mesh_fns:
-                self._mesh_fns[key] = make_sharded_topk(mesh, k,
-                                                        corpus_axes=axes)
-            fn, _ = self._mesh_fns[key]
+            if k not in self._mesh_fns:
+                self._mesh_fns[k], _ = make_sharded_topk(mesh, k,
+                                                         corpus_axes=axes)
+            fn = self._mesh_fns[k]
             if self._mesh_arrays is None or self._mesh_arrays[0] != epoch:
-                vecs = jnp.asarray(
-                    np.concatenate([s["vectors"] for s in snaps], axis=0))
-                live = jnp.asarray(
-                    np.concatenate([s["live"] for s in snaps]))
+                # each shard's rows go straight to its own device
+                rows = NamedSharding(mesh, P(axes))
+                vecs = jax.device_put(
+                    np.concatenate([s["vectors"] for s in snaps], axis=0),
+                    rows)
+                live = jax.device_put(
+                    np.concatenate([s["live"] for s in snaps]), rows)
                 self._mesh_arrays = (epoch, vecs, live)
             _, vecs, live = self._mesh_arrays
         # the device computation itself runs lock-free: vecs/live are
         # immutable device arrays pinned to this epoch's snapshot
         s, gi = fn(q, vecs, live)
         with self._mu:
-            self.counters["mesh_searches"] += 1
+            self.counters["mesh_searches"] += q.shape[0]
         return np.asarray(s), np.asarray(gi)
 
     # -- payloads / stats --------------------------------------------------
@@ -380,6 +393,13 @@ class ShardedVectorDB(DBInstance):
 def make_sharded_db(n_shards: int = 4, index_type: str = "ivf",
                     quant: str = "none", dim: int = 384,
                     **kw) -> ShardedVectorDB:
-    return ShardedVectorDB(ShardedDBConfig(
-        n_shards=n_shards, index_type=index_type, quant=quant, dim=dim,
-        **kw))
+    """A flat/none corpus with one shard per device is spread over all
+    devices on a ``("data",)`` mesh; any other layout merges on the host."""
+    cfg = ShardedDBConfig(n_shards=n_shards, index_type=index_type,
+                          quant=quant, dim=dim, **kw)
+    mesh = None
+    if ((index_type, quant) == ("flat", "none") and n_shards > 1
+            and n_shards == jax.device_count()
+            and "data" in cfg.corpus_axes):
+        mesh = make_mesh((n_shards,), ("data",))
+    return ShardedVectorDB(cfg, mesh=mesh)

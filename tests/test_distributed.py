@@ -169,15 +169,15 @@ def test_local_topk_pads_when_k_exceeds_rows():
 
 _SHARDED_DB_PROG = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import sys; sys.path.insert(0, "src")
+from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 from repro.core.interfaces import Chunk
-from repro.distributed.sharding import sharding_rules
 from repro.launch.mesh import make_mesh
 from repro.sharded import ShardedDBConfig, ShardedVectorDB
 
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4,), ("data",))
 rng = np.random.default_rng(0)
 N, d, k = 480, 32, 6
 vecs = rng.standard_normal((N, d)).astype(np.float32)
@@ -187,37 +187,59 @@ top_ref = np.argsort(-(q @ vecs.T), axis=1)[:, :k]
 
 db = ShardedVectorDB(ShardedDBConfig(
     n_shards=4, index_type="flat", dim=d, capacity=1024,
-    corpus_axes=("data",)))
+    corpus_axes=("data",)), mesh=mesh)
 db.insert(vecs, chunks)
-with sharding_rules(mesh):
-    res = db.search(q, k)
-assert db.counters["mesh_searches"] == 1, db.counters
+# the mesh belongs to the database: a serving worker thread takes the
+# mesh path without entering any mesh context of its own
+with ThreadPoolExecutor(1) as pool:
+    res = pool.submit(db.search, q, k).result()
+assert db.counters["mesh_searches"] == 5, db.counters
 for i, r in enumerate(res):
     got = {db.get_chunk(c).text for c in r.chunk_ids if c >= 0}
     assert got == {f"c{j}" for j in top_ref[i]}, (i, got)
+# the stacked corpus is row-sharded: each device holds one shard's rows
+_, stacked, _ = db._mesh_arrays
+rows = {s.device: s.data.shape[0] for s in stacked.addressable_shards}
+assert len(rows) == 4 and set(rows.values()) == {stacked.shape[0] // 4}, rows
 # mutations invalidate the device-resident stack: remove then re-search
 db.remove(int(top_ref[0][0]) // 4)
-with sharding_rules(mesh):
-    res2 = db.search(q, k)
-assert db.counters["mesh_searches"] == 2
+res2 = db.search(q, k)
+assert db.counters["mesh_searches"] == 10
 gone = {f"c{j}" for j in range((top_ref[0][0] // 4) * 4,
                                (top_ref[0][0] // 4) * 4 + 4)}
 for r in res2:
     assert not ({db.get_chunk(c).text for c in r.chunk_ids if c >= 0} & gone)
-# without an active mesh the same db falls back to the host-side merge
+# without a mesh the same db falls back to the host-side merge
+db.mesh = None
 res3 = db.search(q, k)
-assert db.counters["mesh_searches"] == 2
+assert db.counters["mesh_searches"] == 10
 assert [set(r.chunk_ids.tolist()) for r in res3] == \
     [set(r.chunk_ids.tolist()) for r in res2]
+# the registered factory lays a flat corpus with one shard per device over
+# the devices itself, so a spec-built database takes the mesh path
+from repro.core.registry import create
+fdb = create("vectordb", "sharded", n_shards=4, index_type="flat", dim=d,
+             capacity=1024)
+assert dict(fdb.mesh.shape) == {"data": 4}, fdb.mesh
+fdb.insert(vecs, [Chunk(chunk_id=-1, doc_id=i // 4, text=f"c{i}")
+                  for i in range(N)])
+with ThreadPoolExecutor(1) as pool:
+    fres = pool.submit(fdb.search, q, k).result()
+assert fdb.counters["mesh_searches"] == 5, fdb.counters
+assert [{fdb.get_chunk(c).text for c in r.chunk_ids} for r in fres] == \
+    [{f"c{j}" for j in t} for t in top_ref]
+assert create("vectordb", "sharded", n_shards=4, index_type="ivf", dim=d,
+              capacity=1024).mesh is None
 print("SHARDED_DB_MESH_OK")
 """
 
 
 @pytest.mark.slow
 def test_sharded_db_multidevice_subprocess():
-    """ShardedVectorDB's fused shard_map path on 8 fake host devices:
-    exact flat top-k, epoch invalidation on mutation, and host-merge
-    fallback parity when no mesh is active."""
+    """ShardedVectorDB's fused shard_map path on 4 fake host devices:
+    exact flat top-k searched from a worker thread, one shard per device,
+    epoch invalidation on mutation, and host-merge fallback parity when
+    the database has no mesh."""
     r = subprocess.run([sys.executable, "-c", _SHARDED_DB_PROG],
                        capture_output=True, text=True, timeout=300,
                        cwd=__file__.rsplit("/tests/", 1)[0])

@@ -19,6 +19,7 @@ from repro.core.interfaces import Chunk
 from repro.core.spec import PipelineSpec
 from repro.core.vectordb import (DBConfig, JaxVectorDB, kernel_ladder,
                                  make_fused_db)
+from repro.kernels.ref import topk_mismatch
 from repro.roofline.retrieve import RetrieveShape, hbm_bytes, roofline
 from repro.sharded import ShardedDBConfig, ShardedVectorDB
 
@@ -55,7 +56,7 @@ def _queries(nq=8, seed=1):
     return q.astype(np.float32)
 
 
-# -- fused vs reference ladder, bit-exact, pre and post mutation ------------
+# -- fused vs reference ladder, pre and post mutation -----------------------
 
 
 @pytest.mark.parametrize("index_type,quant", [
@@ -74,8 +75,31 @@ def test_fused_matches_reference_db(index_type, quant, env_mode, monkeypatch):
                 db.insert(fresh.copy(), _chunks(10, doc0=900))
         sa, ia = ref._search_arrays(q, 5)
         sb, ib = fus._search_arrays(q, 5)
+        # the cross-mode contract (repro.kernels.ref.SCORE_RTOL): equal
+        # ids, scores equal up to f32 reduction order
         assert (np.asarray(ia) == np.asarray(ib)).all(), phase
-        assert (np.asarray(sa) == np.asarray(sb)).all(), phase
+        assert topk_mismatch(sa, ia, sb, ib) is None, phase
+
+
+_S = np.array([[0.9, 0.5, 0.5, -3.0e38]], np.float32)
+_I = np.array([[4, 7, 2, -1]], np.int32)
+
+
+@pytest.mark.parametrize("s_b,i_b,violation", [
+    (_S + 1e-6, _I, None),                       # reduction-order drift
+    (_S + 1e-3, _I, "scores differ"),
+    (_S, _I[:, [0, 2, 1, 3]], "ids differ"),     # a tie may not swap
+    (_S, np.array([[4, 7, 9, -1]]), "ids differ"),
+    (_S, np.array([[4, 7, 2, 3]]), "ids differ"),
+])
+def test_topk_contract(s_b, i_b, violation):
+    bad = topk_mismatch(_S, _I, s_b, i_b)
+    assert (bad is None) if violation is None else (violation in bad)
+
+
+def test_topk_contract_rejects_a_duplicate_id():
+    i = np.array([[4, 7, 7, -1]])
+    assert "twice" in topk_mismatch(_S, i, _S, i)
 
 
 def test_packed_mirror_refreshed_by_rebuild(monkeypatch):
@@ -95,7 +119,7 @@ def test_packed_mirror_refreshed_by_rebuild(monkeypatch):
     sa, ia = ref._search_arrays(q, 5)
     sb, ib = fus._search_arrays(q, 5)
     assert (np.asarray(ia) == np.asarray(ib)).all()
-    assert (np.asarray(sa) == np.asarray(sb)).all()
+    assert topk_mismatch(sa, ia, sb, ib) is None
 
 
 # -- sharded composition ----------------------------------------------------
@@ -114,7 +138,8 @@ def test_sharded_fused_matches_sharded_unfused(monkeypatch):
         dbs.append(db)
     for a, b in zip(dbs[0].search(_queries(), 6), dbs[1].search(_queries(), 6)):
         assert (a.chunk_ids == b.chunk_ids).all()
-        np.testing.assert_array_equal(a.scores, b.scores)
+        assert topk_mismatch(a.scores[None], a.chunk_ids[None],
+                             b.scores[None], b.chunk_ids[None]) is None
 
 
 # -- registry / spec seams --------------------------------------------------

@@ -299,3 +299,25 @@ def test_shard_scale_scenario_spec_selects_sharded_backend():
     pspec = spec.pipeline_spec()
     assert pspec.vectordb.component == "sharded"
     assert pspec.vectordb.options["n_shards"] == 4
+
+
+@pytest.mark.parametrize("kw", [
+    dict(n_shards=2),                                # 2 shards, 1 device
+    dict(index_type="ivf"),                          # mesh scans flat only
+    dict(quant="sq8"),
+    dict(corpus_axes=("pod",)),                      # no corpus axis on it
+])
+def test_explicit_mesh_that_does_not_fit_raises(kw):
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
+    cfg = dict(n_shards=1, index_type="flat", quant="none", dim=DIM,
+               capacity=256)
+    ShardedVectorDB(ShardedDBConfig(**cfg), mesh=mesh)   # fits: no error
+    with pytest.raises(ValueError, match="one shard per device"):
+        ShardedVectorDB(ShardedDBConfig(**{**cfg, **kw}), mesh=mesh)
+
+
+def test_factory_makes_no_mesh_when_shards_and_devices_differ():
+    db = make_sharded_db(n_shards=2, index_type="flat", dim=DIM,
+                         capacity=256)
+    assert db.mesh is None
