@@ -10,7 +10,7 @@ import traceback
 
 from benchmarks import (accuracy_eval, chaos, elastic_scaling, fused_retrieve,
                         gen_engine, index_schemes, indexing_breakdown,
-                        monitor_overhead, overhead, query_breakdown,
+                        monitor_overhead, query_breakdown,
                         resource_limits, resource_utilization, scenarios,
                         sensitivity, serving, sharded_retrieval,
                         stage_pipeline, update_workload)
@@ -33,7 +33,6 @@ MODULES = {
     "scenarios": scenarios,                   # named scenario suite (sim mode)
     "chaos": chaos,                           # fault injection + recovery
     "sharded_retrieval": sharded_retrieval,   # corpus scaling at flat p99
-    "overhead": overhead,                     # tracing on/off A-B gate
     "fused_retrieve": fused_retrieve,         # fused-kernel retrieve gate
 }
 

@@ -55,11 +55,6 @@ echo "== tier-1: fused retrieve gate (parity + roofline + latency) =="
 # bound, and a micro-batch latency win on the sq8/pq xla paths
 python -m benchmarks.fused_retrieve --smoke --check > /dev/null
 
-echo "== tier-1: tracing overhead gate (on/off A-B, pinned budget) =="
-# --check asserts: span recording costs <=3% throughput and <=3% p99 on
-# the steady scenario served live through the elastic executor
-python -m benchmarks.overhead --smoke --check > /dev/null
-
 echo "== tier-1: trace export smoke (sim spans -> Chrome trace) =="
 # deterministic sim trace written as Chrome trace_event JSON + JSONL,
 # then structurally validated by the exporter CLI

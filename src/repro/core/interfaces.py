@@ -93,6 +93,14 @@ class BaseLLM(abc.ABC):
                  contexts: Sequence[Sequence[Chunk]]) -> List[str]:
         """Generate final answers given a batch of prompts and contexts."""
 
+    def generate_timed(self, prompts: Sequence[str],
+                       contexts: Sequence[Sequence[Chunk]]
+                       ) -> Tuple[List[str], List[float]]:
+        """``generate``, and each answer's first-token time on the
+        ``perf_counter`` clock: 0.0 where the generator stamps none."""
+        answers = self.generate(prompts, contexts)
+        return answers, [0.0] * len(answers)
+
 
 @dataclass
 class StageTrace:
@@ -109,3 +117,5 @@ class StageTrace:
     # attempts the request took through the elastic retry path (1 = clean
     # first pass); latency_s accumulates every attempt's service time
     n_attempts: int = 1
+    # perf_counter time of the answer's first token (0.0: not stamped)
+    first_token_s: float = 0.0

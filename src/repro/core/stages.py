@@ -46,6 +46,9 @@ class QueryBatch:
     contexts: Optional[List[List[Chunk]]] = None    # after rerank
     reranked_ids: Optional[List[List[int]]] = None  # after rerank
     answers: Optional[List[str]] = None             # after generate
+    # perf_counter time of each answer's first token, after generate
+    # (0.0 where the generator stamps none)
+    first_token_s: List[float] = field(default_factory=list)
     latency_s: Dict[str, float] = field(default_factory=dict)  # per-request
 
     def __post_init__(self):
@@ -180,7 +183,8 @@ class GenerateStage(Stage):
     def _apply(self, batch: QueryBatch) -> None:
         assert batch.contexts is not None, \
             "GenerateStage needs RerankStage output"
-        batch.answers = self.llm.generate(batch.questions, batch.contexts)
+        batch.answers, batch.first_token_s = self.llm.generate_timed(
+            batch.questions, batch.contexts)
 
     def replica_copy(self) -> "GenerateStage":
         """Per-replica engines: an LLM exposing ``clone()`` (ModelLLM /
@@ -217,6 +221,8 @@ def traces_from_batch(batch: QueryBatch,
             gold_chunk_ids=list(batch.gold_chunks[i]),
             latency_s=latency_s[i] if latency_s else dict(batch.latency_s),
             n_attempts=n_attempts[i] if n_attempts else 1,
+            first_token_s=(batch.first_token_s[i] if batch.first_token_s
+                           else 0.0),
         ))
     return traces
 

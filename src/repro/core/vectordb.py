@@ -22,12 +22,13 @@ import threading
 import time
 from dataclasses import dataclass
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.interfaces import Chunk, DBInstance, SearchResult
 from repro.core.registry import register
 from repro.kernels import ops as kops
@@ -287,12 +288,15 @@ class JaxVectorDB(DBInstance):
         # profiling counters (read by the monitor)
         self.counters: Dict[str, float] = {   # guarded-by: _mu
             "inserts": 0, "removals": 0, "searches": 0, "rebuilds": 0,
-            "fused_searches": 0,
+            "fused_searches": 0, "h2d_bytes": 0,
             "insert_time_s": 0.0, "build_time_s": 0.0, "search_time_s": 0.0,
             "flat_fill": 0.0,
         }
         if cfg.quant == "pq":
             assert d % cfg.pq_m == 0, (d, cfg.pq_m)
+        # optional obs.Tracer for the search's own spans (serve.py
+        # --trace-out attaches one)
+        self.tracer = None
 
     # -- writes ------------------------------------------------------------
 
@@ -466,16 +470,18 @@ class JaxVectorDB(DBInstance):
 
     def search(self, vectors: np.ndarray, k: int) -> List[SearchResult]:
         t0 = time.perf_counter()
-        q = jnp.asarray(vectors, jnp.float32)
+        q = (vectors if isinstance(vectors, jax.Array)
+             else np.asarray(vectors, np.float32))
         scores, idx = self._search_arrays(q, k)
         with self._mu:   # concurrent retrieval replicas share the counters
             self.counters["searches"] += len(vectors)
             if self._kernel == "fused":
                 self.counters["fused_searches"] += len(vectors)
             self.counters["search_time_s"] += time.perf_counter() - t0
-        return [SearchResult(chunk_ids=np.asarray(idx[i]),
-                             scores=np.asarray(scores[i]))
-                for i in range(len(vectors))]
+        with obs.span("stage.retrieval.merge", self.tracer):
+            return [SearchResult(chunk_ids=np.asarray(idx[i]),
+                                 scores=np.asarray(scores[i]))
+                    for i in range(len(vectors))]
 
     def _snapshot(self) -> Dict[str, object]:
         """Grab a consistent view of all search-relevant index state.
@@ -507,84 +513,109 @@ class JaxVectorDB(DBInstance):
 
         Callers that coordinate several databases — the sharded wrapper —
         take every snapshot under one lock first, then score outside it.
+
+        Every host array the search hands the device (the queries, the
+        index arrays, the masks) is sent before the first launch, in one
+        ``stage.retrieval.h2d`` span, and its bytes are counted in
+        ``db.h2d_bytes``; an array already on the device counts nothing.
         """
+        tr = self.tracer
+        with obs.span("stage.retrieval.snapshot", tr):
+            if snap is None:
+                snap = self._snapshot()
+            launches = self._launches(k, snap)
+        nbytes = sum(a.nbytes for a in [q] + [a for _, args in launches
+                                               for a in args]
+                     if isinstance(a, np.ndarray))
+        with obs.span("stage.retrieval.h2d", tr, bytes=nbytes):
+            qd = jnp.asarray(q, jnp.float32)
+            dev = [[jnp.asarray(a) for a in args] for _, args in launches]
+        obs.count("db.h2d_bytes", nbytes)
+        obs.count("db.search_calls")
+        with self._mu:
+            self.counters["h2d_bytes"] += nbytes
+        with obs.span("stage.retrieval.launch", tr):
+            outs = [call(qd, *args)
+                    for (call, _), args in zip(launches, dev)]
+        with obs.span("stage.retrieval.wait", tr):
+            outs = [(np.asarray(s), np.asarray(i)) for s, i in outs]
+        if len(outs) == 1:
+            return outs[0]
+        with obs.span("stage.retrieval.merge", tr):
+            return merge_topk(*outs[0], *outs[1], k)
+
+    def _launches(self, k: int, snap: Dict[str, object]
+                  ) -> List[Tuple[Callable, Sequence]]:
+        """The search programs to run against ``snap``, each as ``(call,
+        host arrays)``, run as ``call(q, *those arrays on the device)``:
+        the main index, then (hybrid) the fresh rows' linear scan."""
         cfg = self.cfg
-        if snap is None:
-            snap = self._snapshot()
         # kernel mode resolved here, OUTSIDE the jitted primitives, and
         # threaded through as a static argument (dispatch contract in
         # repro.kernels.ops: an env read at trace time goes stale)
         mode = kops.kernel_mode()
         live, indexed = snap["live"], snap["indexed"]
-        main_live = live & indexed if cfg.use_hybrid else live
         if not snap["built"]:
             # index never built: brute-force everything (cold start)
-            s, i = _flat_search(q, jnp.asarray(snap["vectors"]),
-                                jnp.asarray(live), k, self._kernel, mode)
-            return np.asarray(s), np.asarray(i)
-        s_main, i_main = self._search_main(q, main_live, k, snap, mode)
-        if not cfg.use_hybrid:
-            return np.asarray(s_main), np.asarray(i_main)
-        fresh = live & ~indexed
-        if not fresh.any():
-            return np.asarray(s_main), np.asarray(i_main)
-        # linear scan of the temp flat buffer (the paper's freshness path)
-        s_fl, i_fl = _flat_search(q, jnp.asarray(snap["vectors"]),
-                                  jnp.asarray(fresh), k, self._kernel, mode)
-        return merge_topk(np.asarray(s_main), np.asarray(i_main),
-                          np.asarray(s_fl), np.asarray(i_fl), k)
+            return [self._flat_launch(snap["vectors"], live, k, mode)]
+        main_live = live & indexed if cfg.use_hybrid else live
+        out = [self._main_launch(main_live, k, snap, mode)]
+        if cfg.use_hybrid:
+            fresh = live & ~indexed
+            if fresh.any():
+                # linear scan of the temp flat buffer (the paper's
+                # freshness path)
+                out.append(self._flat_launch(snap["vectors"], fresh, k,
+                                             mode))
+        return out
 
-    def _search_main(self, q, main_live: np.ndarray, k: int,
-                     snap: Dict[str, object], mode: str):
+    def _flat_launch(self, vectors: np.ndarray, mask: np.ndarray, k: int,
+                     mode: str) -> Tuple[Callable, Sequence]:
+        kernel = self._kernel
+        return (lambda q, *a: _flat_search(q, *a, k, kernel, mode),
+                (vectors, mask))
+
+    def _main_launch(self, main_live: np.ndarray, k: int,
+                     snap: Dict[str, object], mode: str
+                     ) -> Tuple[Callable, Sequence]:
         cfg = self.cfg
         # ladder values are sized for the global nlist; a row-partitioned
         # shard has proportionally fewer lists, so clamp
         nprobe = min(int(snap["nprobe"]), cfg.nlist)
-        live = jnp.asarray(main_live)
+        kernel = self._kernel
         if cfg.index_type == "flat":
             if cfg.quant == "sq8" and snap["sq_codes"] is not None:
-                return _sq8_flat_search(q, jnp.asarray(snap["sq_codes"]),
-                                        jnp.asarray(snap["sq_scale"]),
-                                        live, k, self._kernel, mode)
-            return _flat_search(q, jnp.asarray(snap["vectors"]), live, k,
-                                self._kernel, mode)
-        if self._kernel == "fused" and snap["packed"] is not None:
-            return self._search_main_fused(q, main_live, nprobe, k, snap,
-                                           mode)
+                return (lambda q, *a: _sq8_flat_search(q, *a, k, kernel,
+                                                       mode),
+                        (snap["sq_codes"], snap["sq_scale"], main_live))
+            return self._flat_launch(snap["vectors"], main_live, k, mode)
+        if kernel == "fused" and snap["packed"] is not None:
+            # fused IVF/PQ probe over the packed mirror (one kernel
+            # launch).  The mirror rows are immutable between rebuilds, so
+            # post-snapshot mutations are reflected exactly as in the
+            # unfused path: through the liveness mask alone.  ``ok`` is
+            # recomputed per search from the snapshot's copied masks — a
+            # tombstone lands as ``ok=0`` on the dead row, identical to
+            # ``_ivf_search`` masking it to NEG.
+            packed = snap["packed"]
+            slot = packed["slot"]
+            ok = ((slot >= 0) & main_live[np.maximum(slot, 0)]).astype(
+                np.int32)
+            if cfg.quant == "pq" and packed.get("codes") is not None:
+                return (lambda q, *a: kops.fused_pq_topk(q, *a, nprobe, k,
+                                                         mode=mode),
+                        (snap["pq_codebook"], snap["centroids"],
+                         packed["codes"], slot, ok))
+            return (lambda q, *a: kops.fused_ivf_topk(q, *a, nprobe, k,
+                                                      mode=mode),
+                    (snap["centroids"], packed["vecs"], slot, ok))
+        index = (snap["centroids"], snap["buckets"], snap["bucket_live"])
         if cfg.quant == "pq" and snap["pq_codes"] is not None:
-            return _pq_ivf_search(
-                q, jnp.asarray(snap["pq_codes"]),
-                jnp.asarray(snap["pq_codebook"]),
-                live, jnp.asarray(snap["centroids"]),
-                jnp.asarray(snap["buckets"]),
-                jnp.asarray(snap["bucket_live"]), nprobe, k)
-        return _ivf_search(q, jnp.asarray(snap["vectors"]), live,
-                           jnp.asarray(snap["centroids"]),
-                           jnp.asarray(snap["buckets"]),
-                           jnp.asarray(snap["bucket_live"]), nprobe, k)
-
-    def _search_main_fused(self, q, main_live: np.ndarray, nprobe: int,
-                           k: int, snap: Dict[str, object], mode: str):
-        """Fused IVF/PQ probe over the packed mirror (one kernel launch).
-
-        The mirror rows are immutable between rebuilds, so post-snapshot
-        mutations are reflected exactly as in the unfused path: through the
-        liveness mask alone.  ``ok`` is recomputed per search from the
-        snapshot's copied masks — a tombstone lands as ``ok=0`` on the dead
-        row, identical to ``_ivf_search`` masking it to NEG.
-        """
-        packed = snap["packed"]
-        slot = packed["slot"]
-        ok = ((slot >= 0) & main_live[np.maximum(slot, 0)]).astype(np.int32)
-        if self.cfg.quant == "pq" and packed.get("codes") is not None:
-            return kops.fused_pq_topk(
-                q, jnp.asarray(snap["pq_codebook"]),
-                jnp.asarray(snap["centroids"]),
-                jnp.asarray(packed["codes"]), jnp.asarray(slot),
-                jnp.asarray(ok), nprobe, k, mode=mode)
-        return kops.fused_ivf_topk(
-            q, jnp.asarray(snap["centroids"]), jnp.asarray(packed["vecs"]),
-            jnp.asarray(slot), jnp.asarray(ok), nprobe, k, mode=mode)
+            return (lambda q, *a: _pq_ivf_search(q, *a, nprobe, k),
+                    (snap["pq_codes"], snap["pq_codebook"], main_live)
+                    + index)
+        return (lambda q, *a: _ivf_search(q, *a, nprobe, k),
+                (snap["vectors"], main_live) + index)
 
     # -- misc --------------------------------------------------------------
 

@@ -6,13 +6,19 @@ controller's ``ScaleEvent`` stream each lived on their own clock and
 schema.  The registry absorbs all of them as ``MetricPoint``s on a single
 timeline (the tracer's clock), so a controller decision lands next to the
 request spans it caused and one exporter renders everything.
+
+A registry that lives as long as the process (``repro.obs.REGISTRY``) is
+built with ``ring``: each name then keeps only its newest ``ring`` points and
+observations, so a long-running server holds a bounded amount.
 """
 # analysis: deterministic -- timestamps come only from the injected clock
 from __future__ import annotations
 
+import itertools
 import threading
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Tuple
 
 from repro.serving.accounting import percentile
 
@@ -36,22 +42,36 @@ class MetricsRegistry:
     * counters — monotone accumulators; each ``counter_add`` records the
       running total as a timeline point;
     * gauges   — instantaneous values (``gauge_set``);
-    * histograms — value reservoirs summarized via ``histogram_summary``
-      (p50/p95/p99/mean), off the timeline;
+    * histograms — timestamped value reservoirs summarized via
+      ``histogram_summary`` (p50/p95/p99/mean), off the timeline;
     * events   — annotated instants (autoscale decisions, faults).
+
+    ``ring`` (None: unbounded) caps each name's points and observations at
+    its newest ``ring``; a counter's running total is kept whole.
     """
 
-    def __init__(self, clock=None):
+    def __init__(self, clock=None, ring: Optional[int] = None):
         self.clock = clock
-        self._points: List[MetricPoint] = []      # guarded-by: _lock
+        self.ring = ring
+        # per name: (insertion number, MetricPoint); the number keeps the
+        # timeline's order among equal timestamps
+        self._points: Dict[str, Deque] = {}       # guarded-by: _lock
+        self._seq = itertools.count()             # guarded-by: _lock
         self._counters: Dict[str, float] = {}     # guarded-by: _lock
-        self._hist: Dict[str, List[float]] = {}   # guarded-by: _lock
+        # per name: (t, value)
+        self._hist: Dict[str, Deque] = {}         # guarded-by: _lock
         self._lock = threading.Lock()
 
     def _now(self, t: Optional[float]) -> float:
         if t is not None:
             return float(t)
         return self.clock.now() if self.clock is not None else 0.0
+
+    def _append(self, point: MetricPoint) -> None:  # locked-by: _lock
+        ring = self._points.get(point.name)
+        if ring is None:
+            ring = self._points[point.name] = deque(maxlen=self.ring)
+        ring.append((next(self._seq), point))
 
     # -- recording ----------------------------------------------------------
 
@@ -60,24 +80,28 @@ class MetricsRegistry:
         with self._lock:
             total = self._counters.get(name, 0.0) + float(delta)
             self._counters[name] = total
-            self._points.append(MetricPoint(self._now(t), name, total,
-                                            kind="counter"))
+            self._append(MetricPoint(self._now(t), name, total,
+                                     kind="counter"))
         return total
 
     def gauge_set(self, name: str, value: float,
                   t: Optional[float] = None) -> None:
         with self._lock:
-            self._points.append(MetricPoint(self._now(t), name,
-                                            float(value), kind="gauge"))
+            self._append(MetricPoint(self._now(t), name, float(value),
+                                     kind="gauge"))
 
-    def observe(self, name: str, value: float) -> None:
+    def observe(self, name: str, value: float,
+                t: Optional[float] = None) -> None:
         with self._lock:
-            self._hist.setdefault(name, []).append(float(value))
+            ring = self._hist.get(name)
+            if ring is None:
+                ring = self._hist[name] = deque(maxlen=self.ring)
+            ring.append((self._now(t), float(value)))
 
     def event(self, name: str, t: Optional[float] = None, **args) -> None:
         with self._lock:
-            self._points.append(MetricPoint(self._now(t), name, 1.0,
-                                            kind="event", args=args))
+            self._append(MetricPoint(self._now(t), name, 1.0, kind="event",
+                                     args=args))
 
     # -- absorption (the unification surface) -------------------------------
 
@@ -91,8 +115,8 @@ class MetricsRegistry:
             ts, vs = buf.values()
             with self._lock:
                 for t, v in zip(ts, vs):
-                    self._points.append(MetricPoint(float(t) - anchor, name,
-                                                    float(v), kind="gauge"))
+                    self._append(MetricPoint(float(t) - anchor, name,
+                                             float(v), kind="gauge"))
 
     def absorb_stage_rows(self, rows, t: Optional[float] = None) -> None:
         """One ``StageStats.row()`` set (or sim stage rows) as gauges."""
@@ -122,25 +146,29 @@ class MetricsRegistry:
     def timeline(self) -> List[MetricPoint]:
         """Every point, time-ordered (stable for equal timestamps)."""
         with self._lock:
-            pts = list(self._points)
-        return sorted(pts, key=lambda p: p.t)
+            pts = [sp for ring in self._points.values() for sp in ring]
+        return [p for _, p in sorted(pts, key=lambda sp: (sp[1].t, sp[0]))]
 
     def series(self, name: str) -> List[MetricPoint]:
         with self._lock:
-            return [p for p in self._points if p.name == name]
+            return [p for _, p in self._points.get(name, ())]
 
     def counter_value(self, name: str) -> float:
         with self._lock:
             return self._counters.get(name, 0.0)
 
     def histogram_summary(self, name: str) -> Dict[str, float]:
-        with self._lock:
-            xs = list(self._hist.get(name, []))
+        xs = [v for _, v in self.observations(name)]
         if not xs:
             return {"n": 0.0}
         return {"n": float(len(xs)), "mean": sum(xs) / len(xs),
                 "p50": percentile(xs, 50), "p95": percentile(xs, 95),
                 "p99": percentile(xs, 99)}
+
+    def observations(self, name: str) -> List[Tuple[float, float]]:
+        """``(t, value)`` of each observation of ``name`` still held."""
+        with self._lock:
+            return list(self._hist.get(name, ()))
 
     def histogram_names(self) -> List[str]:
         with self._lock:

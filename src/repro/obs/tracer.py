@@ -15,15 +15,28 @@ Overhead contract: instrumented code paths hold the tracer as an Optional
 and skip *all* bookkeeping when it is ``None``; when present, recording is
 one plain list append — atomic under CPython's GIL, so the hot path takes
 no lock and replica workers never convoy on the tracer at batch
-boundaries (``benchmarks/overhead.py`` gates the cost at <=3%
-throughput/p99 on the ``steady`` scenario).
+boundaries.
+
+The served path's own spans go through ``span``: a
+``jax.profiler.TraceAnnotation`` always (about half a microsecond when no
+profile is being taken; under the profiler it lands on the device trace's
+clock), and a ``Span`` on the ``Tracer`` the caller holds, if any.  Its
+counters and observations go to ``REGISTRY`` (``count``, ``observe``), the
+process's own bounded registry on the raw ``perf_counter`` timebase.  What
+tracing costs is measured on the chip by the benchmark (PERF.md), not on
+the CPU.
 """
 from __future__ import annotations
 
 
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+from repro.obs.metrics import MetricsRegistry
 
 
 class WallClock:
@@ -170,3 +183,41 @@ def attach_pipeline(tracer: Optional[Tracer], pipeline) -> None:
     pipeline.tracer = tracer
     for st in pipeline.stages:
         st.tracer = tracer
+
+
+def span(name: str, tracer: Optional[Tracer] = None, **meta):
+    """Context manager for one span of the served path.
+
+    Always a ``TraceAnnotation`` named ``name`` with ``meta`` as its stats,
+    so the span shares the device trace's clock when a profile is being
+    taken; when ``tracer`` is given, also a ``Span`` there, on the
+    tracer's clock, with the name's first dotted part as its category and
+    track and ``meta`` as its args."""
+    if tracer is None:
+        return TraceAnnotation(name, **meta)
+    return _both(tracer, name, meta)
+
+
+@contextmanager
+def _both(tracer: Tracer, name: str, meta: Dict[str, object]
+          ) -> Iterator[None]:
+    layer = name.partition(".")[0]
+    with TraceAnnotation(name, **meta), tracer.span(name, cat=layer,
+                                                     tid=layer, **meta):
+        yield
+
+
+#: the process's counters and observations, timestamped on the raw
+#: ``perf_counter`` timebase that the serving harness stamps requests
+#: with; each name keeps its newest 2**16 points
+REGISTRY = MetricsRegistry(clock=WallClock(anchor=0.0), ring=1 << 16)
+
+
+def count(name: str, delta: float = 1.0) -> None:
+    """Add ``delta`` to the process counter ``name``."""
+    REGISTRY.counter_add(name, delta)
+
+
+def observe(name: str, value: float) -> None:
+    """Record one timestamped observation of ``name`` in the process."""
+    REGISTRY.observe(name, value)

@@ -48,6 +48,9 @@ class RequestRecord:
     batch_size: int = 1
     ok: bool = True
     stages: Dict[str, float] = field(default_factory=dict)
+    # the answer's first token (lock-step path with a generator that stamps
+    # it); 0.0 where none is stamped, as in the elastic path
+    first_token_s: float = 0.0
 
     @property
     def queue_wait_s(self) -> float:

@@ -20,6 +20,14 @@ KV-cache slots:
 * **per-request metrics** — TTFT is measured per request from its submitted
   arrival time to its first token, TPOT from its own decode cadence; samples
   land in a thread-safe ``GenStats`` (replica engines may share one).
+* **spans** — each step's parts are ``repro.obs.span``s, on the device
+  trace's clock under the profiler (and on an attached ``Tracer``):
+  ``engine.admit``; ``engine.prefill.launch`` and
+  ``engine.prefill.first_token``; ``engine.decode.upload`` (positions and
+  tokens to the device), ``.launch`` (the decode program and its argmax),
+  ``.sync`` (the next tokens back to the host) and ``.bookkeep`` (the
+  per-slot loop and retirement).  The jitted programs are named
+  ``gen_decode_step`` and ``gen_prefill_chunk``.
 
 Greedy decode attends only within a sequence's own cache row, so the engine
 is **output-identical** to the lock-step ``ModelLLM`` (same seed, same
@@ -43,13 +51,13 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from functools import partial
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.generator import (PER_ROW_POS_FAMILIES, GenStats, ModelLLM,
                                   build_prompt, render_tokens)
 from repro.core.interfaces import BaseLLM, Chunk
@@ -111,10 +119,17 @@ class _EngineCore:
         self.params = (params if params is not None
                        else self.model.init(jax.random.PRNGKey(seed), cfg))
         self.tok = HashTokenizer(cfg.vocab_size)
-        self._decode = jax.jit(partial(self.model.decode_step, cfg=cfg))
-        self._chunk = jax.jit(self._prefill_slot)
+        decode_step = self.model.decode_step
 
-    def _prefill_slot(self, params, tokens, k, v, slot, offset):
+        def gen_decode_step(params, batch, cache):
+            return decode_step(params, cfg=cfg, batch=batch, cache=cache)
+
+        # named programs: the trace shows ``jit_gen_decode_step`` and
+        # ``jit_gen_prefill_chunk``
+        self._decode = jax.jit(gen_decode_step)
+        self._chunk = jax.jit(self.gen_prefill_chunk)
+
+    def gen_prefill_chunk(self, params, tokens, k, v, slot, offset):
         """Prefill one chunk of one slot inside the pooled cache: slice the
         slot's row, run the chunk, write the row back."""
         row = {"k": jax.lax.dynamic_slice_in_dim(k, slot, 1, axis=1),
@@ -240,7 +255,9 @@ class GenEngine:
         """One scheduling iteration: admit → prefill budget → one decode
         step → retire.  Returns True if any work was done."""
         self.n_steps += 1
-        self._admit()
+        if self._free and self._queue:   # a span only where one moves
+            with obs.span("engine.admit", self.tracer):
+                self._admit()
         did = self._prefill_work()
         did = self._decode_work() or did
         return did
@@ -296,29 +313,25 @@ class GenEngine:
         n = len(chunk)
         if n < C:                       # right-pad the final chunk; padded
             chunk = np.pad(chunk, (0, C - n))  # K/V is never attended
-        logits, self.cache["k"], self.cache["v"] = self.core._chunk(
-            self.core.params, jnp.asarray(chunk[None]),
-            self.cache["k"], self.cache["v"],
-            jnp.asarray(req.slot, jnp.int32), jnp.asarray(off, jnp.int32))
+        tr = self.tracer
+        with obs.span("engine.prefill.launch", tr, rid=req.rid, chunks=k):
+            logits, self.cache["k"], self.cache["v"] = self.core._chunk(
+                self.core.params, jnp.asarray(chunk[None]),
+                self.cache["k"], self.cache["v"],
+                jnp.asarray(req.slot, jnp.int32), jnp.asarray(off, jnp.int32))
         self.n_prefill_chunks += k
         req.filled = off + n
-        tr = self.tracer
-        if tr is not None:
-            tr.instant("gen.prefill_chunk", cat="gen", tid="gen",
-                       rid=req.rid, chunks=k, filled=req.filled)
         # park the slot's decode position at the *next* write offset: a
         # ride-along decode write lands exactly where the next real write
         # (chunk or first decode token) will overwrite it
         self._pos[req.slot] = req.filled
         if req.filled >= req.prompt_len:
             # final chunk: the last real token's logits give the first token
-            first = int(np.asarray(
-                jnp.argmax(logits[0, req.prompt_len - 1 - off])))
+            with obs.span("engine.prefill.first_token", tr, rid=req.rid):
+                first = int(np.asarray(
+                    jnp.argmax(logits[0, req.prompt_len - 1 - off])))
             req.out.append(first)
             req.t_first = time.perf_counter()
-            if tr is not None:
-                tr.instant("gen.first_token", cat="gen", tid="gen",
-                           rid=req.rid)
             req.state = "decode"
             self._cur[req.slot] = first
             self._pos[req.slot] = req.prompt_len
@@ -341,31 +354,33 @@ class GenEngine:
         active = self._decode_slots()
         if not active:
             return False
-        self.cache["pos"] = jnp.asarray(self._pos)
-        batch = {"tokens": jnp.asarray(self._cur[:, None])}
-        logits, self.cache = self.core._decode(
-            self.core.params, batch=batch, cache=self.cache)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        now = time.perf_counter()
-        self.n_decode_steps += 1
-        for s in active:
-            req = self._slot_req[s]
-            req.out.append(int(nxt[s]))
-            self._cur[s] = int(nxt[s])
-            self._pos[s] += 1
-            if len(req.out) >= req.max_new:
-                req.t_done = now
-                self._retire(req)
+        tr = self.tracer
+        with obs.span("engine.decode.upload", tr):
+            self.cache["pos"] = jnp.asarray(self._pos)
+            batch = {"tokens": jnp.asarray(self._cur[:, None])}
+        with obs.span("engine.decode.launch", tr):
+            logits, self.cache = self.core._decode(
+                self.core.params, batch=batch, cache=self.cache)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with obs.span("engine.decode.sync", tr):
+            nxt = np.asarray(nxt)
+        with obs.span("engine.decode.bookkeep", tr):
+            now = time.perf_counter()
+            self.n_decode_steps += 1
+            for s in active:
+                req = self._slot_req[s]
+                req.out.append(int(nxt[s]))
+                self._cur[s] = int(nxt[s])
+                self._pos[s] += 1
+                if len(req.out) >= req.max_new:
+                    req.t_done = now
+                    self._retire(req)
         return True
 
     def _retire(self, req: GenRequest) -> None:
         if req.t_done == 0.0:
             req.t_done = time.perf_counter()
         req.state = "done"
-        tr = self.tracer
-        if tr is not None:
-            tr.instant("gen.retire", cat="gen", tid="gen",
-                       rid=req.rid, tokens=len(req.out))
         self.stats.record(req.ttft_s, req.tpot_s, len(req.out))
         self._slot_req[req.slot] = None
         self._free.append(req.slot)
@@ -375,15 +390,23 @@ class GenEngine:
 
     def run(self, prompts: Sequence[str]) -> List[str]:
         """Submit every prompt now, step to completion, return the decoded
-        answer strings in submission order.  Batch mode owns its records:
-        they are popped after rendering so a long-running serving loop of
-        ``generate`` calls holds no per-request state (service-mode callers
-        driving ``submit``/``step`` pop ``records[rid]`` themselves)."""
+        answer strings in submission order."""
+        return self.run_timed(prompts)[0]
+
+    def run_timed(self, prompts: Sequence[str]
+                  ) -> Tuple[List[str], List[float]]:
+        """``run``, and each prompt's first-token time (``perf_counter``).
+        Batch mode owns its records: they are popped after rendering so a
+        long-running serving loop of ``generate`` calls holds no
+        per-request state (service-mode callers driving ``submit``/``step``
+        pop ``records[rid]`` themselves)."""
         t0 = time.perf_counter()
         rids = [self.submit(p, t_arrive=t0) for p in prompts]
         while self.busy():
             self.step()
-        return [render_tokens(self.records.pop(r).out) for r in rids]
+        reqs = [self.records.pop(r) for r in rids]
+        return ([render_tokens(r.out) for r in reqs],
+                [r.t_first for r in reqs])
 
 
 class EngineLLM(BaseLLM):
@@ -420,8 +443,13 @@ class EngineLLM(BaseLLM):
 
     def generate(self, prompts: Sequence[str],
                  contexts: Sequence[Sequence[Chunk]]) -> List[str]:
+        return self.generate_timed(prompts, contexts)[0]
+
+    def generate_timed(self, prompts: Sequence[str],
+                       contexts: Sequence[Sequence[Chunk]]
+                       ) -> Tuple[List[str], List[float]]:
         texts = [build_prompt(p, c) for p, c in zip(prompts, contexts)]
-        return self.engine.run(texts)
+        return self.engine.run_timed(texts)
 
 
 def engine_from_model_llm(llm: ModelLLM, **kw) -> GenEngine:

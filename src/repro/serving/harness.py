@@ -27,6 +27,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterator, List, Optional
 
+from repro import obs
 from repro.core.pipeline import RAGPipeline
 from repro.core.registry import build
 from repro.core.spec import PipelineSpec
@@ -133,6 +134,10 @@ class ServingHarness:
             sub.record.start_s = sub.record.end_s
         sub.record.ok = ok
         sub.error = err
+        if ok and sub.record.first_token_s > 0.0:
+            # time to first token from the submission
+            obs.observe("serve.ttft_s",
+                        sub.record.first_token_s - sub.record.arrival_s)
         tr = self.tracer
         if tr is not None:
             te = tr.now()
@@ -186,14 +191,17 @@ class ServingHarness:
             sub.record.batch_size = len(batch)
         self.batch_sizes.append(len(batch))
         stage_before = self.pipeline.timer.breakdown()
+        first_token_s = [0.0] * len(batch)
         try:
             if batch[0].request.op == "query":
                 reqs = [s.request for s in batch]
                 golds = [gold_chunks_for(self.pipeline.db, r.gold_doc_id,
                                          r.answer) for r in reqs]
-                self.pipeline.query([r.question for r in reqs],
-                                    ground_truth=[r.answer for r in reqs],
-                                    gold_chunks=golds)
+                traces = self.pipeline.query(
+                    [r.question for r in reqs],
+                    ground_truth=[r.answer for r in reqs],
+                    gold_chunks=golds)
+                first_token_s = [t.first_token_s for t in traces]
             else:
                 req = batch[0].request
                 if req.op == "insert":
@@ -220,8 +228,9 @@ class ServingHarness:
                  / len(batch)
                  for k in stage_after
                  if stage_after.get(k, 0.0) > stage_before.get(k, 0.0)}
-        for sub in batch:
+        for sub, t_first in zip(batch, first_token_s):
             sub.record.stages = dict(share)
+            sub.record.first_token_s = t_first
             self._finish(sub, ok=True)
 
     def _executor_loop(self) -> None:
