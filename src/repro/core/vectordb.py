@@ -13,7 +13,9 @@ buffer into the main index (paper §5.5 reproduces the latency sawtooth this
 creates).  Removals are tombstones until the next rebuild.
 
 All heavy scoring runs in jitted JAX (optionally via the Pallas kernels in
-``repro.kernels``); bookkeeping (payloads, id maps) is host-side numpy.
+``repro.kernels``); bookkeeping (payloads, id maps) is host-side numpy.  The
+host arrays are the source of truth; the index arrays a search reads stay on
+the device between searches, as copies refreshed when the host's change.
 """
 from __future__ import annotations
 
@@ -187,6 +189,28 @@ def _pq_ivf_search(q, codes, codebook, live, cent, buckets, bucket_live,
     return top, idx
 
 
+@jax.jit
+def _put_rows(dev, rows, lo):
+    """``dev`` with ``rows`` written from row ``lo``, as a new array: a
+    snapshot not yet launched may still hold ``dev``."""
+    return jax.lax.dynamic_update_slice(dev, rows, (lo, 0))
+
+
+# each search program's arguments after the queries: index arrays by name
+# (``packed.*`` the fused kernels' mirror), the ``mask`` of rows it scans,
+# or the fused kernels' ``ok``, made from that mask per packed row
+_ARGS = {
+    "flat": ("vectors", "mask"),
+    "sq8": ("sq_codes", "sq_scale", "mask"),
+    "ivf": ("vectors", "mask", "centroids", "buckets", "bucket_live"),
+    "pq_ivf": ("pq_codes", "pq_codebook", "mask", "centroids", "buckets",
+               "bucket_live"),
+    "fused_ivf": ("centroids", "packed.vecs", "packed.slot", "ok"),
+    "fused_pq": ("pq_codebook", "centroids", "packed.codes", "packed.slot",
+                 "ok"),
+}
+
+
 def merge_topk(scores_a, idx_a, scores_b, idx_b, k: int):
     """Merge two top-k lists (used for hybrid main+flat and sharded search).
 
@@ -259,6 +283,10 @@ class JaxVectorDB(DBInstance):
     are not yet live, (b) flip ``live``/``indexed`` bits, or (c) swap whole
     index arrays — so a search running against its snapshot sees a
     consistent (possibly slightly stale) view, never a torn one.
+
+    The snapshot also brings the device copies of the index arrays the
+    search reads up to date (``_refresh``), so a search hands the device
+    only its queries and masks unless a writer changed what it reads.
     """
 
     def __init__(self, cfg: DBConfig):
@@ -285,6 +313,9 @@ class JaxVectorDB(DBInstance):
         # vectors/codes); rebuilt wholesale with the buckets, rows are
         # immutable in between (inserts always take fresh slots)
         self.packed: Optional[Dict[str, np.ndarray]] = None  # guarded-by: _mu
+        # device copies of the index arrays searches read, by ``_ARGS``
+        # name: (the host array copied, its device copy, ``n_slots`` then)
+        self._mirror: Dict[str, Tuple[np.ndarray, jax.Array, int]] = {}  # guarded-by: _mu
         # profiling counters (read by the monitor)
         self.counters: Dict[str, float] = {   # guarded-by: _mu
             "inserts": 0, "removals": 0, "searches": 0, "rebuilds": 0,
@@ -483,16 +514,19 @@ class JaxVectorDB(DBInstance):
                                  scores=np.asarray(scores[i]))
                     for i in range(len(vectors))]
 
-    def _snapshot(self) -> Dict[str, object]:
+    def _snapshot(self, resident: bool = True) -> Dict[str, object]:
         """Grab a consistent view of all search-relevant index state.
 
         Mask arrays are copied (writers flip their bits in place); index
         arrays are captured by reference (writers swap whole objects).
         ``vectors`` is referenced, not copied — rows mutated after the
         snapshot belong to slots that are non-live in the copied masks.
+        ``plan`` names the programs the search runs (``_plan``); unless
+        ``resident`` is false, ``dev`` holds the device copies of the
+        index arrays they read, brought up to date here.
         """
         with self._mu:
-            return {
+            snap = {
                 "built": self._main_built(),
                 "live": self.live.copy(),
                 "indexed": self.indexed.copy(),
@@ -504,7 +538,54 @@ class JaxVectorDB(DBInstance):
                 "pq_codes": self.pq_codes, "pq_codebook": self.pq_codebook,
                 "packed": self.packed,
                 "nprobe": self.cfg.nprobe,
+                "dev": {},
             }
+            snap["plan"] = self._plan(snap)
+            if resident:
+                with obs.span("stage.retrieval.refresh", self.tracer):
+                    snap["dev"] = self._refresh(
+                        {key for prog, _ in snap["plan"]
+                         for key in _ARGS[prog] if key not in ("mask", "ok")})
+            return snap
+
+    def _refresh(self, keys) -> Dict[str, jax.Array]:  # locked-by: _mu
+        """Device copies of the index arrays ``keys`` as the host holds them.
+
+        Writers replace these arrays whole, so a copy is current while its
+        host object is — except ``vectors``, into which ``insert`` only
+        appends rows: its copy takes the rows appended since it was made.
+        (On the CPU a copy may share the host's buffer; rows a snapshot
+        counts live never change, so searches answer alike.)
+        The bytes sent count in ``db.h2d_bytes``; the call counts as a
+        ``db.resident_hits`` if nothing was sent, else a
+        ``db.resident_pushes``.
+        """
+        out, sent = {}, 0
+        for key in keys:
+            host = (self.packed[key[len("packed."):]]
+                    if key.startswith("packed.") else getattr(self, key))
+            src, dev, n = self._mirror.get(key, (None, None, 0))
+            if src is host and (n == self.n_slots or key != "vectors"):
+                out[key] = dev
+                continue
+            if src is host and n:
+                rows = host[n:self.n_slots]
+                dev = _put_rows(dev, rows, n)
+            else:
+                # a new array, or every used row new: copy it whole, the
+                # stale copy released first so HBM never holds both
+                self._mirror.pop(key, None)
+                del dev
+                rows = host
+                dev = jnp.asarray(host)
+            sent += rows.nbytes
+            self._mirror[key] = (host, dev, self.n_slots)
+            out[key] = dev
+        obs.count("db.resident_pushes" if sent else "db.resident_hits")
+        if sent:
+            obs.count("db.h2d_bytes", sent)
+            self.counters["h2d_bytes"] += sent
+        return out
 
     def _search_arrays(self, q, k: int,
                        snap: Optional[Dict[str, object]] = None
@@ -515,9 +596,10 @@ class JaxVectorDB(DBInstance):
         take every snapshot under one lock first, then score outside it.
 
         Every host array the search hands the device (the queries, the
-        index arrays, the masks) is sent before the first launch, in one
-        ``stage.retrieval.h2d`` span, and its bytes are counted in
-        ``db.h2d_bytes``; an array already on the device counts nothing.
+        masks, an index array the snapshot holds no device copy of) is
+        sent before the first launch, in one ``stage.retrieval.h2d`` span,
+        and its bytes are counted in ``db.h2d_bytes``; an array already on
+        the device counts nothing.
         """
         tr = self.tracer
         with obs.span("stage.retrieval.snapshot", tr):
@@ -544,78 +626,85 @@ class JaxVectorDB(DBInstance):
         with obs.span("stage.retrieval.merge", tr):
             return merge_topk(*outs[0], *outs[1], k)
 
+    def _plan(self, snap: Dict[str, object]) -> List[Tuple[str, str]]:
+        """The ``_ARGS`` programs a search of ``snap`` runs, each with the
+        mask it scans: the main index, then (hybrid) the fresh rows'
+        linear scan."""
+        cfg = self.cfg
+        if not snap["built"]:
+            # index never built: brute-force everything (cold start)
+            return [("flat", "live")]
+        pq = cfg.quant == "pq"
+        if cfg.index_type == "flat":
+            sq8 = cfg.quant == "sq8" and snap["sq_codes"] is not None
+            main = "sq8" if sq8 else "flat"
+        elif self._kernel == "fused" and snap["packed"] is not None:
+            # fused IVF/PQ probe over the packed mirror (one kernel launch)
+            main = ("fused_pq" if pq and "codes" in snap["packed"]
+                    else "fused_ivf")
+        else:
+            main = "pq_ivf" if pq and snap["pq_codes"] is not None else "ivf"
+        if not cfg.use_hybrid:
+            return [(main, "live")]
+        plan = [(main, "main")]
+        if (snap["live"] & ~snap["indexed"]).any():
+            # linear scan of the temp flat buffer (the paper's freshness
+            # path)
+            plan.append(("flat", "fresh"))
+        return plan
+
     def _launches(self, k: int, snap: Dict[str, object]
                   ) -> List[Tuple[Callable, Sequence]]:
-        """The search programs to run against ``snap``, each as ``(call,
-        host arrays)``, run as ``call(q, *those arrays on the device)``:
-        the main index, then (hybrid) the fresh rows' linear scan."""
+        """The programs of ``snap["plan"]``, each as ``(call, arrays)``,
+        run as ``call(q, *those arrays on the device)``."""
         cfg = self.cfg
         # kernel mode resolved here, OUTSIDE the jitted primitives, and
         # threaded through as a static argument (dispatch contract in
         # repro.kernels.ops: an env read at trace time goes stale)
         mode = kops.kernel_mode()
-        live, indexed = snap["live"], snap["indexed"]
-        if not snap["built"]:
-            # index never built: brute-force everything (cold start)
-            return [self._flat_launch(snap["vectors"], live, k, mode)]
-        main_live = live & indexed if cfg.use_hybrid else live
-        out = [self._main_launch(main_live, k, snap, mode)]
-        if cfg.use_hybrid:
-            fresh = live & ~indexed
-            if fresh.any():
-                # linear scan of the temp flat buffer (the paper's
-                # freshness path)
-                out.append(self._flat_launch(snap["vectors"], fresh, k,
-                                             mode))
-        return out
-
-    def _flat_launch(self, vectors: np.ndarray, mask: np.ndarray, k: int,
-                     mode: str) -> Tuple[Callable, Sequence]:
-        kernel = self._kernel
-        return (lambda q, *a: _flat_search(q, *a, k, kernel, mode),
-                (vectors, mask))
-
-    def _main_launch(self, main_live: np.ndarray, k: int,
-                     snap: Dict[str, object], mode: str
-                     ) -> Tuple[Callable, Sequence]:
-        cfg = self.cfg
         # ladder values are sized for the global nlist; a row-partitioned
         # shard has proportionally fewer lists, so clamp
         nprobe = min(int(snap["nprobe"]), cfg.nlist)
         kernel = self._kernel
-        if cfg.index_type == "flat":
-            if cfg.quant == "sq8" and snap["sq_codes"] is not None:
-                return (lambda q, *a: _sq8_flat_search(q, *a, k, kernel,
-                                                       mode),
-                        (snap["sq_codes"], snap["sq_scale"], main_live))
-            return self._flat_launch(snap["vectors"], main_live, k, mode)
-        if kernel == "fused" and snap["packed"] is not None:
-            # fused IVF/PQ probe over the packed mirror (one kernel
-            # launch).  The mirror rows are immutable between rebuilds, so
-            # post-snapshot mutations are reflected exactly as in the
-            # unfused path: through the liveness mask alone.  ``ok`` is
-            # recomputed per search from the snapshot's copied masks — a
-            # tombstone lands as ``ok=0`` on the dead row, identical to
-            # ``_ivf_search`` masking it to NEG.
-            packed = snap["packed"]
-            slot = packed["slot"]
-            ok = ((slot >= 0) & main_live[np.maximum(slot, 0)]).astype(
-                np.int32)
-            if cfg.quant == "pq" and packed.get("codes") is not None:
-                return (lambda q, *a: kops.fused_pq_topk(q, *a, nprobe, k,
-                                                         mode=mode),
-                        (snap["pq_codebook"], snap["centroids"],
-                         packed["codes"], slot, ok))
-            return (lambda q, *a: kops.fused_ivf_topk(q, *a, nprobe, k,
-                                                      mode=mode),
-                    (snap["centroids"], packed["vecs"], slot, ok))
-        index = (snap["centroids"], snap["buckets"], snap["bucket_live"])
-        if cfg.quant == "pq" and snap["pq_codes"] is not None:
-            return (lambda q, *a: _pq_ivf_search(q, *a, nprobe, k),
-                    (snap["pq_codes"], snap["pq_codebook"], main_live)
-                    + index)
-        return (lambda q, *a: _ivf_search(q, *a, nprobe, k),
-                (snap["vectors"], main_live) + index)
+        calls = {
+            "flat": lambda q, *a: _flat_search(q, *a, k, kernel, mode),
+            "sq8": lambda q, *a: _sq8_flat_search(q, *a, k, kernel, mode),
+            "ivf": lambda q, *a: _ivf_search(q, *a, nprobe, k),
+            "pq_ivf": lambda q, *a: _pq_ivf_search(q, *a, nprobe, k),
+            "fused_ivf": lambda q, *a: kops.fused_ivf_topk(
+                q, *a, nprobe, k, mode=mode),
+            "fused_pq": lambda q, *a: kops.fused_pq_topk(
+                q, *a, nprobe, k, mode=mode),
+        }
+        live, indexed = snap["live"], snap["indexed"]
+        masks = {"live": live}
+        if cfg.use_hybrid:
+            masks.update(main=live & indexed, fresh=live & ~indexed)
+        out = []
+        for prog, mask in snap["plan"]:
+            args = []
+            for key in _ARGS[prog]:
+                if key == "mask":
+                    args.append(masks[mask])
+                elif key == "ok":
+                    # the packed mirror's rows are immutable between
+                    # rebuilds, so post-snapshot mutations are reflected
+                    # exactly as in the unfused path: through the
+                    # liveness mask alone.  A tombstone lands as ``ok=0``
+                    # on the dead row, identical to ``_ivf_search``
+                    # masking it to NEG.
+                    slot = snap["packed"]["slot"]
+                    args.append(((slot >= 0)
+                                 & masks[mask][np.maximum(slot, 0)]
+                                 ).astype(np.int32))
+                elif key in snap["dev"]:
+                    args.append(snap["dev"][key])
+                elif key.startswith("packed."):
+                    args.append(snap["packed"][key[len("packed."):]])
+                else:
+                    args.append(snap[key])
+            out.append((calls[prog], args))
+        return out
 
     # -- misc --------------------------------------------------------------
 

@@ -253,8 +253,10 @@ class ShardedVectorDB(DBInstance):
     def search(self, vectors: np.ndarray, k: int) -> List[SearchResult]:
         t0 = time.perf_counter()
         q = jnp.asarray(vectors, jnp.float32)
-        with self._mu:   # consistent cross-shard snapshot
-            snaps = [sh._snapshot() for sh in self.shards]
+        with self._mu:   # consistent cross-shard snapshot; the mesh path
+            # places host rows itself, so its shards keep no device copies
+            snaps = [sh._snapshot(resident=self.mesh is None)
+                     for sh in self.shards]
             epoch = self._epoch
         out = self._mesh_search(q, k, snaps, epoch)
         if out is None:

@@ -60,6 +60,65 @@ def test_a_search_counts_the_bytes_it_sends(index_type):
     assert db.stats()["h2d_bytes"] == send
 
 
+def _resident_counts():
+    return [obs.REGISTRY.counter_value(name) for name in
+            ("db.h2d_bytes", "db.resident_hits", "db.resident_pushes")]
+
+
+def _grown_counts(before):
+    return [a - b for a, b in zip(_resident_counts(), before)]
+
+
+@pytest.mark.parametrize("index_type", ["flat", "ivf"])
+def test_a_second_search_sends_only_its_queries_and_masks(index_type):
+    db, v = _db(index_type)
+    q = v[:3]
+    db.search(q, 5)
+    before = _resident_counts()
+    db.search(q, 5)
+    assert _grown_counts(before) == [q.nbytes + db.live.nbytes, 1, 0]
+
+
+@pytest.mark.parametrize("index_type", ["flat", "ivf"])
+def test_a_search_after_an_insert_sends_the_new_rows(index_type):
+    db, v = _db(index_type)
+    q = v[:3]
+    db.search(q, 5)
+    new = v[:7] * 0.5
+    db.insert(new, [Chunk(-1, 1000, "") for _ in range(7)])
+    before = _resident_counts()
+    db.search(q, 5)
+    assert _grown_counts(before) == [
+        q.nbytes + db.live.nbytes + new.nbytes, 0, 1]
+    np.testing.assert_array_equal(np.asarray(db._mirror["vectors"][1]),
+                                  db.vectors)
+
+
+@pytest.mark.parametrize("change", ["insert", "remove"])
+def test_a_sharded_search_sees_a_change_after_its_last(change):
+    from repro.sharded import ShardedDBConfig, ShardedVectorDB
+    db = ShardedVectorDB(ShardedDBConfig(
+        n_shards=2, index_type="flat", dim=DIM, capacity=CAP,
+        use_hybrid=False))
+    v = np.random.default_rng(0).standard_normal((ROWS, DIM))
+    v = (v / np.linalg.norm(v, axis=1, keepdims=True)).astype(np.float32)
+    db.insert(v, [Chunk(-1, i, "") for i in range(ROWS)])
+    db.build_index()
+    q = v[:4] * 0.9 + 0.1 * v[4:8]
+    first = np.stack([r.chunk_ids for r in db.search(q, 3)])
+    if change == "insert":
+        db.insert(q / np.linalg.norm(q, axis=1, keepdims=True),
+                  [Chunk(-1, 5000 + i, "") for i in range(4)])
+        top = np.stack([r.chunk_ids for r in db.search(q, 3)])
+        assert top[:, 0].tolist() == [db.doc_slots[5000 + i][0]
+                                      for i in range(4)]
+    else:
+        for i in range(4):
+            db.remove(i)
+        top = np.stack([r.chunk_ids for r in db.search(q, 3)])
+        assert not np.isin(first[:, 0], top).any()
+
+
 def test_arrays_already_on_the_device_count_nothing():
     db, v = _db("flat")
     host = db._search_arrays(v[:3], 5)
