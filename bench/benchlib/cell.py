@@ -20,7 +20,7 @@ import copy
 import gc
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields, is_dataclass
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -166,7 +166,8 @@ def pipeline_spec(cfg: Dict, seed: int):
     return PipelineSpec.from_dict(d)
 
 
-# the source's (Hugging Face) keys, as the program's model names them
+# the source's (Hugging Face) keys, as the program's configuration names
+# them; a configuration adds its own under ``hf_keys``
 HF_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
            "num_attention_heads": "n_heads",
            "num_key_value_heads": "n_kv_heads", "intermediate_size": "d_ff",
@@ -177,41 +178,68 @@ HF_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
            "rope_scaling": "rope_scaling"}
 
 
+def _flat(block: Dict, prefix: str = "") -> Dict:
+    """A nested block as ``{dotted path: value}``."""
+    out = {}
+    for key, value in block.items():
+        if isinstance(value, dict):
+            out.update(_flat(value, f"{prefix}{key}."))
+        else:
+            out[prefix + key] = value
+    return out
+
+
 def check_model(cfg: Dict) -> None:
-    """The program serves the model the configuration file states: its
-    ``model`` block exactly, and the source's ``hf_config`` but for the
-    departures that ``assumed`` names."""
+    """The program serves the model the configuration file states: every
+    key of its ``model`` block (a key of a nested group by its dotted path,
+    ``<group>.<key>``), and each key of the source's ``hf_config`` that
+    ``HF_KEYS`` or the file's ``hf_keys`` maps to the program's
+    configuration.  A key the program's configuration lacks is an error.
+    Keys named in ``assumed`` (departures) or ``reduced`` (cuts), or whose
+    group is, are not held."""
     m = cfg.get("model")
     if not m:
         return
     from repro import configs as arch_configs
     opts = cfg["pipeline"]["llm"]["options"]
-    mc = (arch_configs.get_smoke(opts["arch"]) if opts.get("smoke")
-          else arch_configs.get_config(opts["arch"]))
-    have = {"n_layers": mc.n_layers, "d_model": mc.d_model,
-            "n_heads": mc.n_heads, "n_kv_heads": mc.n_kv_heads,
-            "d_ff": mc.d_ff, "vocab_size": mc.vocab_size,
-            "head_dim": mc.resolved_head_dim, "rope_theta": mc.rope_theta,
-            "norm_eps": mc.norm_eps, "dtype": mc.dtype,
-            "family": mc.family, "activation": mc.activation,
-            "rope_type": mc.rope_type, "tie_embeddings": mc.tie_embeddings,
-            "attn_window": mc.attn_window,
-            # the program's rotary embedding has no such options: it
-            # rotates every dimension of a head, unscaled
-            "partial_rotary_factor": 1.0, "rope_scaling": None}
-    for key, want in m.items():
-        if key in have and have[key] != want:
-            raise ValueError(f"the program's {opts['arch']} has {key}="
-                             f"{have[key]!r}, the configuration states "
-                             f"{want!r}")
-    stated = cfg.get("assumed", {})
-    for hf_key, key in HF_KEYS.items():
-        hf = cfg.get("hf_config", {})
-        if hf_key in hf and hf_key not in stated and have[key] != hf[hf_key]:
-            raise ValueError(f"the program's {opts['arch']} has {key}="
-                             f"{have[key]!r}, the source's {hf_key} is "
-                             f"{hf[hf_key]!r}, and `assumed` states no "
-                             f"such departure")
+    arch = opts["arch"]
+    mc = (arch_configs.get_smoke(arch) if opts.get("smoke")
+          else arch_configs.get_config(arch))
+    aliases = {"arch": arch, "head_dim": mc.resolved_head_dim,
+               # the program's rotary embedding has no such options: it
+               # rotates every dimension of a head, unscaled
+               "partial_rotary_factor": 1.0, "rope_scaling": None}
+    exempt = set(cfg.get("assumed", {})) | set(cfg.get("reduced", []))
+
+    def held(key: str) -> bool:
+        parts = key.split(".")
+        return not any(".".join(parts[:i]) in exempt
+                       for i in range(1, len(parts) + 1))
+
+    def program(path: str):
+        if path in aliases:
+            return aliases[path]
+        obj = mc
+        for part in path.split("."):
+            if not is_dataclass(obj) or part not in {
+                    f.name for f in fields(obj)}:
+                raise ValueError(f"the program's {arch} has no {path!r}, "
+                                 f"which the configuration states")
+            obj = getattr(obj, part)
+        return obj
+
+    for key, want in _flat(m).items():
+        if held(key) and (have := program(key)) != want:
+            raise ValueError(f"the program's {arch} has {key}={have!r}, "
+                             f"the configuration states {want!r}")
+    hf = cfg.get("hf_config", {})
+    for hf_key, path in dict(HF_KEYS, **cfg.get("hf_keys", {})).items():
+        if (hf_key in hf and held(hf_key)
+                and (have := program(path)) != hf[hf_key]):
+            raise ValueError(f"the program's {arch} has {path}={have!r}, "
+                             f"the source's {hf_key} is "
+                             f"{hf[hf_key]!r}, and neither `assumed` nor "
+                             f"`reduced` names it")
 
 
 @dataclass

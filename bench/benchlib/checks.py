@@ -19,18 +19,21 @@ set from readings of sound runs and of the control, see ``PERF.md``):
   token's reference logit lies below the reference's best at its position.
 
 The controls, for the control script only: ``gen.logit_gap`` of the
-float8-weight reference put in the program's place, and
-``retrieve.score_gap`` of a search at ``precision=HIGH`` put in its place.
+reference in its lower precision (``logits_at(..., fp8=True)``) put in the
+program's place, and ``retrieve.score_gap`` of a search at
+``precision=HIGH`` put in its place.  The generator's reference is the
+module the configuration names (``spec.model_module``).
 """
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchlib import ref_model, ref_retrieve
+from benchlib import ref_retrieve
 from benchlib.cell import vector_blocks
 
 
@@ -117,9 +120,10 @@ def retrieval(cfg: Dict, seed: int, retrievals: List, texts: Optional[List],
     return res
 
 
-def generation(cfg: Dict, seed: int, retired: List,
+def generation(cfg: Dict, seed: int, retired: List, reference: ModuleType,
                control: bool = False) -> Dict:
-    """Numbers of a sample of the window's answers."""
+    """Numbers of a sample of the window's answers, against ``reference``
+    (``make_weights``, ``logits_at``)."""
     m = cfg["model"]
     max_new = int(cfg["pipeline"]["llm"]["options"]["max_new"])
     n_check = int(cfg["check"]["answers"])
@@ -136,8 +140,8 @@ def generation(cfg: Dict, seed: int, retired: List,
         seqs.append(np.concatenate([prompt, np.asarray(out[:-1], np.int32)]))
         at.append(list(range(len(prompt) - 1, len(prompt) - 1 + len(out))))
         served.append(np.asarray(out))
-    w = ref_model.make_weights(m, seed)
-    ref = ref_model.logits_at(m, w, seqs, at)
+    w = reference.make_weights(m, seed)
+    ref = reference.logits_at(m, w, seqs, at)
     tok = np.concatenate(served)
     best = ref.max(axis=1)
     gap = best - ref[np.arange(len(tok)), tok]
@@ -145,7 +149,7 @@ def generation(cfg: Dict, seed: int, retired: List,
                        "gen.logit_gap": float(gap.max())},
            "tokens_compared": int(len(tok))}
     if control:
-        low = ref_model.logits_at(m, w, seqs, at, fp8=True)
+        low = reference.logits_at(m, w, seqs, at, fp8=True)
         first = low.argmax(axis=1)
         res["control"] = {"gen.logit_gap": float(
             (best - ref[np.arange(len(first)), first]).max())}

@@ -4,7 +4,8 @@ Each count is the least the work requires, so a share of a roofline or of
 a peak built on it cannot pass 100% unless the time leaves part of the work
 out:
 
-* a dense GQA transformer (SwiGLU MLP, untied head): FLOPs per token, with
+* a dense GQA transformer (SwiGLU MLP, untied head; ``from_config``, the
+  default ``model_costs`` of a configuration): FLOPs per token, with
   attention over the keys the token sees, and the head only for tokens
   whose logits are used (the last prompt token and each decoded token);
   bytes per decode step: every weight once, the gathered embedding rows,
@@ -78,6 +79,11 @@ class Dense:
         w += len(pos) * self.d_model * self.dtype_bytes      # embedding rows
         kv = self.kv_bytes_per_position()
         return float(w + (sum(pos) + len(pos)) * kv)
+
+
+def from_config(m: Dict) -> Dense:
+    """The count of a configuration's ``model`` block."""
+    return Dense.from_config(m)
 
 
 def flat_launch_bytes(n: int, d: int, nq: int, k: int) -> float:

@@ -140,7 +140,7 @@ def compile_cache() -> None:
 def run_cell(cell, devs, peaks, args) -> dict:
     from benchlib import cell as cell_lib
     from benchlib import checks as checks_lib
-    from benchlib import costs, device, trace as trace_lib
+    from benchlib import device, trace as trace_lib
 
     compile_cache()
     cfg, traffic = cell.config, cell.traffic
@@ -173,8 +173,8 @@ def run_cell(cell, devs, peaks, args) -> dict:
         records=w.records,
         batch_sizes=w.batch_sizes, stage_series=w.stage_series,
         tpot_s=w.tpot_s, log=log, peaks=peaks, peak_bytes=peak, cfg=cfg,
-        model=costs.Dense.from_config(cfg["model"]) if "model" in cfg
-        else None, trace=None, trace_win=None, trace_pc=w.trace_pc)
+        model=cell.costs.from_config(cfg["model"]) if cell.costs else None,
+        trace=None, trace_win=None, trace_pc=w.trace_pc)
     breakdown = dev_extra = None
     if trace_dir is not None:
         tr = trace_lib.load(trace_lib.find_xplane(trace_dir))
@@ -204,9 +204,9 @@ def run_cell(cell, devs, peaks, args) -> dict:
                                control=args.control)
     numbers.update(ret["numbers"])
     controls = dict(ret.get("control", {}))
-    if "model" in cfg:
+    if cell.reference is not None:
         gen = checks_lib.generation(cfg, args.seed, log.retired,
-                                    control=args.control)
+                                    cell.reference, control=args.control)
         numbers.update(gen["numbers"])
         controls.update(gen.get("control", {}))
     v = checks_lib.verdict(numbers, cfg["limits"])

@@ -8,7 +8,7 @@ from bench_helpers import make_checkout
 def cpu_run(monkeypatch, tmp_path):
     """``run.main`` steered to the CPU at the tiny size: the device gate
     accepts the CPU, whose peaks are test values, and the persistent
-    compilation cache stays off."""
+    compilation cache stays off.  ``root`` runs another checkout."""
     import run
     from benchlib import device
     monkeypatch.setattr(run, "PLATFORM", "cpu")
@@ -18,7 +18,7 @@ def cpu_run(monkeypatch, tmp_path):
     monkeypatch.delenv("REPRO_KERNEL_MODE", raising=False)
     root = make_checkout(tmp_path / "checkout")
 
-    def go(workload, *extra, seed=3000000017, seconds=2.0):
+    def go(workload, *extra, seed=3000000017, seconds=2.0, root=root):
         return run.main(["--workload", workload, "--seed", str(seed),
                          "--seconds", str(seconds), *extra], root=root)
     return go

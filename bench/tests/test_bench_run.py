@@ -7,9 +7,10 @@ import sys
 
 import pytest
 
-from bench_helpers import BENCH, REPO
+from bench_helpers import BENCH, REPO, twins
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+TWIN_FILES = twins()
 
 
 def _last(capsys):
@@ -18,10 +19,7 @@ def _last(capsys):
 
 
 @pytest.mark.parametrize("workload,e2e", [
-    ("tiny-rag.poisson", {"answer_p90_ms", "answered_qps", "recall_at_10",
-                          "setup_s"}),
-    ("tiny-flat.poisson", {"answered_qps", "recall_at_10", "setup_s"}),
-])
+    (t["name"], set(t["end_to_end"])) for t in TWIN_FILES])
 def test_end_to_end_run_prints_the_cells_metrics(cpu_run, capsys, workload,
                                                   e2e):
     assert cpu_run(workload, "--trace", "0") == 0
@@ -36,25 +34,37 @@ def test_end_to_end_run_prints_the_cells_metrics(cpu_run, capsys, workload,
 
 
 @pytest.mark.parametrize("workload,layer", [
-    ("tiny-rag.poisson", {"client.late_p90_ms", "harness.queue_wait_p50_ms",
-                          "harness.batch_mean", "stages.host_ms",
-                          "retrieve.batch_ms", "generate.tpot_p50_ms",
-                          "model.mfu", "device.idle_pct"}),
-    ("tiny-flat.poisson", {"client.answer_p90_ms.flat",
-                           "harness.batch_mean.flat", "stages.host_ms.flat",
-                           "retrieve.batch_ms.flat", "retrieve.bw_mfu",
-                           "device.idle_pct.flat"}),
-])
+    (t["name"], set(t["per_layer"])) for t in TWIN_FILES])
 def test_traced_run_prints_the_per_layer_metrics(cpu_run, capsys, workload,
                                                  layer):
-    # the CPU has no device plane and reports no peak, so the kernel and
-    # model-step rooflines and the peak memory find nothing to read here
+    # what the CPU cannot read (no device plane, no peak) each twin file
+    # names under not_read_on_cpu, with the reason
     assert cpu_run(workload, "--trace", "1") == 0
     res = _last(capsys)
     assert res["correct"] is True, res["checks"]
     assert set(res["metrics"]) == layer
     assert {"busy_s", "window_s"} <= set(res["device"])
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_every_cell_has_one_twin():
+    cells = [w["name"] for w in json.loads(
+        (REPO / "BENCHMARK.json").read_text())["workloads"]]
+    assert sorted(t["of"] for t in TWIN_FILES) == sorted(cells)
+
+
+@pytest.mark.parametrize("t", TWIN_FILES, ids=lambda t: t["name"])
+def test_a_twin_accounts_for_each_metric_of_its_cell(t):
+    doc = json.loads((REPO / "BENCHMARK.json").read_text())
+
+    def of_cell(metrics):
+        return {m["name"] for m in metrics
+                if t["of"] in m.get("workloads", [t["of"]])}
+    assert set(t["end_to_end"]) == of_cell(doc["end_to_end"])
+    read, not_read = set(t["per_layer"]), set(t["not_read_on_cpu"])
+    assert not read & not_read
+    assert read | not_read == of_cell(doc["per_layer"])
+    assert all(t["not_read_on_cpu"].values())
 
 
 def test_a_run_without_a_tpu_prints_no_result(capsys):

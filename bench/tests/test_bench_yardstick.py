@@ -9,10 +9,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench_helpers import BENCH, REPO, make_checkout
+from bench_helpers import BENCH, FIXTURES, REPO, make_checkout
 from benchlib import costs, device, ref_model, ref_retrieve, spec
 
 PHI4 = json.loads((BENCH / "configs" / "phi4mini-rag.json").read_text())
+MOE = json.loads((FIXTURES / "moe_cell" / "bench" / "tests" / "fixtures"
+                  / "configs" / "tiny-moe.json").read_text())
 
 
 # -- discovery ---------------------------------------------------------------
@@ -92,6 +94,106 @@ def test_the_configuration_states_the_programs_model():
     wrong["model"]["d_ff"] = 8000
     with pytest.raises(ValueError, match="d_ff"):
         check_model(wrong)
+
+
+# a departure of each kind from the program's qwen3_moe_30b_a3b smoke model
+# (8 experts of width 64), and the key that names it
+DEPARTURES = {
+    "nested": (lambda c: c["model"]["moe"].update(num_experts=16),
+               "moe.num_experts"),
+    "missing": (lambda c: c["model"].update(kv_lora_rank=512),
+                "kv_lora_rank"),
+    "hf_keys": (lambda c: c.update(
+        hf_config={"n_routed_experts": 64},
+        hf_keys={"n_routed_experts": "moe.num_experts"}),
+        "n_routed_experts"),
+}
+
+
+def _departing(kind):
+    cfg = json.loads(json.dumps(MOE))
+    DEPARTURES[kind][0](cfg)
+    return cfg
+
+
+def test_the_moe_fixture_states_the_programs_model():
+    from benchlib.cell import check_model
+    check_model(MOE)
+
+
+@pytest.mark.parametrize("kind", sorted(DEPARTURES))
+def test_check_model_refuses_a_departure_it_is_not_told_of(kind):
+    from benchlib.cell import check_model
+    with pytest.raises(ValueError, match=re.escape(DEPARTURES[kind][1])):
+        check_model(_departing(kind))
+
+
+@pytest.mark.parametrize("named_in", ["reduced", "assumed"])
+@pytest.mark.parametrize("kind", sorted(DEPARTURES))
+def test_check_model_accepts_a_departure_named_in_the_file(kind, named_in):
+    from benchlib.cell import check_model
+    cfg = _departing(kind)
+    key = DEPARTURES[kind][1]
+    if named_in == "reduced":
+        cfg["reduced"] = [key]
+    else:
+        cfg["assumed"] = {key: "a departure the file states"}
+    check_model(cfg)
+
+
+def test_a_group_named_in_reduced_exempts_its_keys():
+    from benchlib.cell import check_model
+    cfg = _departing("nested")
+    cfg["reduced"] = ["moe"]
+    check_model(cfg)
+
+
+def _cells_with_and_without_the_default_names(tmp_path):
+    root = make_checkout(tmp_path / "co")
+    default = spec.load_cell("tiny-rag.poisson", root, root / "bench")
+    path = root / "bench" / "configs" / "tiny-rag.json"
+    cfg = json.loads(path.read_text())
+    path.write_text(json.dumps(dict(cfg, model_reference="ref_model",
+                                    model_costs="costs")))
+    return default, spec.load_cell("tiny-rag.poisson", root, root / "bench")
+
+
+def test_naming_the_default_reference_and_count_changes_no_number(tmp_path):
+    from benchlib import checks
+    default, named = _cells_with_and_without_the_default_names(tmp_path)
+    assert "model_reference" not in default.config
+    assert named.config["model_reference"] == "ref_model"
+    rng = np.random.default_rng(4)
+    retired = [(0.0, rng.integers(4, 512, size=n).astype(np.int32),
+                rng.integers(4, 512, size=4).tolist()) for n in (30, 9, 17)]
+    got = [checks.generation(c.config, 11, retired, c.reference,
+                             control=True) for c in (default, named)]
+    assert got[0] == got[1]
+    assert got[0]["numbers"]["gen.logit_gap"] > 0
+    m = default.config["model"]
+    counts = [(x.token_flops(40, True), x.token_flops(7, False),
+               x.decode_step_bytes([3, 9]), x.kv_bytes_per_position(),
+               x.head_params)
+              for x in (default.costs.from_config(m),
+                        named.costs.from_config(m),
+                        costs.Dense.from_config(m))]
+    assert counts[0] == counts[1] == counts[2]
+
+
+@pytest.mark.parametrize("key,name", [
+    ("model_reference", "no_such_reference"),
+    ("model_costs", "no_such_count"),
+    ("model_reference", "../metrics/model.mfu"),
+    ("model_costs", "trace"),              # a module without from_config
+])
+def test_an_unknown_model_module_is_refused_when_the_cell_loads(
+        tmp_path, key, name):
+    root = make_checkout(tmp_path / "co")
+    path = root / "bench" / "configs" / "tiny-rag.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    **{key: name})))
+    with pytest.raises(spec.SpecError, match=key):
+        spec.load_cell("tiny-rag.poisson", root, root / "bench")
 
 
 # -- device ------------------------------------------------------------------
